@@ -12,9 +12,9 @@ import time
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
 
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.serve.engine import ServeEngine
@@ -22,7 +22,7 @@ from repro.serve.engine import ServeEngine
 
 def main() -> None:
     cfg = configs.get_smoke("granite-8b").replace(dtype="float32")
-    mesh = Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
+    mesh = make_mesh(jax.devices(), (-1, 1))
     rules = shd.make_rules(multi_pod=False)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
 

@@ -1,56 +1,50 @@
 """RG-LRU linear recurrence as a Pallas TPU kernel.
 
 Grid ``(batch, chunks)`` with the chunk dimension sequential; the hidden
-state (one (W,) vector per batch element) is carried in VMEM scratch. Each
-step loads a (Q × W) tile of per-step coefficients (a, b), composes the
-affine maps within the chunk by a log₂(Q)-step associative scan on the VPU
-(elementwise muls/adds — there is no matmul in this op, so the kernel is
-purely bandwidth-bound and the win is keeping the state resident in VMEM
-instead of re-reading it per step), applies the carried state, and writes
-the (Q × W) output tile. W tiles at the 128-lane register width.
+state (one (1, W) row per batch element) is carried in VMEM scratch. Each
+step loads a (Q × W) tile of per-step coefficients (a, b), widens it to
+float32 in VMEM scratch, and walks its rows in order, h = a_t·h + b_t, with
+one (1, W) load, multiply-add and store per row (``fori_loop`` over dynamic
+row slices; Mosaic lowers no ``associative_scan`` inside a kernel). There
+is no matmul in this op: it is bandwidth-bound, and the win is keeping the
+state resident in VMEM instead of re-reading it per step. W tiles at the
+128-lane register width.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 __all__ = ["lru_scan_kernel"]
 
 
-def _kernel(a_ref, b_ref, y_ref, h_scr):
-    c = pl.program_id(1)
-
-    @pl.when(c == 0)
+def _kernel(a_ref, b_ref, y_ref, h_scr, a_scr, b_scr, y_scr):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    a = a_ref[0].astype(jnp.float32)    # (Q, W)
-    b = b_ref[0].astype(jnp.float32)    # (Q, W)
+    a_scr[...] = a_ref[0].astype(jnp.float32)       # (Q, W)
+    b_scr[...] = b_ref[0].astype(jnp.float32)
 
-    def combine(left, right):
-        a1, b1 = left
-        a2, b2 = right
-        return a1 * a2, a2 * b1 + b2
+    def rows(g, h):                                 # 8 rows per trip
+        for i in range(8):
+            t = pl.ds(g * 8 + i, 1)
+            h = a_scr[t, :] * h + b_scr[t, :]
+            y_scr[t, :] = h
+        return h
 
-    comp_a, comp_b = jax.lax.associative_scan(combine, (a, b), axis=0)
-    h0 = h_scr[...]                      # (1, W)
-    h_seq = comp_b + comp_a * h0
-    y_ref[0] = h_seq.astype(y_ref.dtype)
-    h_scr[...] = h_seq[-1:, :]
+    h_scr[...] = jax.lax.fori_loop(0, a_scr.shape[0] // 8, rows, h_scr[...])
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
 
 def lru_scan_kernel(a: jax.Array, b: jax.Array, *, chunk: int = 128,
                     interpret: bool = True) -> jax.Array:
     """a, b: (B, S, W); returns h: (B, S, W) with h_t = a_t h_{t-1} + b_t."""
     Bsz, S, W = a.shape
-    Q = min(chunk, S)
+    Q = min(chunk, -(-S // 8) * 8)       # rows come in groups of 8
     pad = (-S) % Q
     if pad:
         # pad with identity steps (a=1, b=0) so the carry passes through
@@ -67,8 +61,9 @@ def lru_scan_kernel(a: jax.Array, b: jax.Array, *, chunk: int = 128,
         ],
         out_specs=pl.BlockSpec((1, Q, W), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)]
+        + [pltpu.VMEM((Q, W), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -9,39 +9,43 @@ the intra-chunk quadratic form on the MXU (Q×N @ N×Q and Q×Q @ Q×P matmuls,
 Q and N chosen 128-aligned), and updates the carried state with one more
 MXU product. All state math is float32; a_t = exp(dt·A) < 1 keeps every
 decay factor in (0,1], so no log-space rescue is needed.
+
+Layout: the wrapper moves heads ahead of the sequence, ``x`` (B,S,H,P) →
+(B,H,S,P), so every block's last two dims are (Q, P) and meet the (8, 128)
+tiling rule (P equals the full dim). The per-chunk cumulative decay
+``cum = cumsum(dt·A)`` is formed by XLA outside the kernel and handed in as
+a column (Q, 1) and as a row (1, Q): the quadratic form needs both
+orientations, and this keeps transposes and scans out of the kernel.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 __all__ = ["ssd_kernel"]
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
-            num_chunks: int):
-    c = pl.program_id(2)
-
-    @pl.when(c == 0)
+def _kernel(x_ref, dt_ref, cum_ref, cum_row_ref, b_ref, c_ref, y_ref,
+            state_scr):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (Q,)
-    A = a_ref[0].astype(jnp.float32)                 # scalar
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    dt = dt_ref[0, 0]                                # (Q, 1)
+    cum = cum_ref[0, 0]                              # (Q, 1)
+    cum_row = cum_row_ref[0, 0]                      # (1, Q)
     Bm = b_ref[0].astype(jnp.float32)                # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)                # (Q, N)
-
-    dA = dt * A                                      # (Q,) ≤ 0
-    cum = jnp.cumsum(dA)                             # (Q,)
-    u = dt[:, None] * x                              # (Q, P)
+    # cum at the chunk's last step, (1, 1); a masked lane-sum rather than a
+    # slice, which Mosaic cannot broadcast from lane Q-1
+    lane = jax.lax.broadcasted_iota(jnp.int32, cum_row.shape, 1)
+    last = lane == x.shape[0] - 1
+    cum_end = jnp.sum(jnp.where(last, cum_row, 0.0), axis=1, keepdims=True)
+    u = dt * x                                       # (Q, P)
 
     # intra-chunk quadratic form on the MXU
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
@@ -50,24 +54,21 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
     # poison the vjp with inf·0 — same guard as ref.py)
     iota_i = jax.lax.broadcasted_iota(jnp.int32, CB.shape, 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, CB.shape, 1)
-    diff = cum[:, None] - cum[None, :]
-    L = jnp.exp(jnp.where(iota_j <= iota_i, diff, -jnp.inf))
-    scores = CB * L
-    y_intra = jax.lax.dot_general(scores, u, (((1,), (0,)), ((), ())),
+    L = jnp.exp(jnp.where(iota_j <= iota_i, cum - cum_row, -jnp.inf))
+    y_intra = jax.lax.dot_general(CB * L, u, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)  # (Q,P)
 
     # inter-chunk contribution of the carried state (P, N)
     state = state_scr[...]
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum) * jax.lax.dot_general(
         Cm, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Q, N)·(P, N)^T → (Q, P)
 
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h' = exp(cum_Q) h + Σ_j exp(cum_Q - cum_j) u_j ⊗ B_j
-    decay_end = jnp.exp(cum[-1] - cum)               # (Q,)
-    ud = u * decay_end[:, None]                      # (Q, P)
-    state_scr[...] = (jnp.exp(cum[-1]) * state
+    ud = u * jnp.exp(cum_end - cum)                  # (Q, P)
+    state_scr[...] = (jnp.exp(cum_end) * state
                       + jax.lax.dot_general(ud, Bm, (((0,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32))
 
@@ -88,21 +89,30 @@ def ssd_kernel(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     T = x.shape[1]
     nc = T // Q
 
+    dtf = jnp.swapaxes(dt.astype(jnp.float32), 1, 2)             # (B,H,T)
+    dA = (dtf * A.astype(jnp.float32)[None, :, None]).reshape(Bsz, H, nc, Q)
+    cum = jnp.cumsum(dA, axis=-1).reshape(Bsz, H, T)             # per chunk
+    xt = jnp.swapaxes(x, 1, 2)                                   # (B,H,T,P)
+
+    def col(width):
+        return pl.BlockSpec((1, 1, Q, width), lambda b, h, c: (b, h, c, 0))
+
     y = pl.pallas_call(
-        functools.partial(_kernel, num_chunks=nc),
+        _kernel,
         grid=(Bsz, H, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            col(P),
+            col(1),
+            col(1),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_specs=col(P),
+        out_shape=jax.ShapeDtypeStruct(xt.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
-    return y[:, :S]
+    )(xt, dtf[..., None], cum[..., None], cum[:, :, None, :], Bm, Cm)
+    return jnp.swapaxes(y, 1, 2)[:, :S]

@@ -16,14 +16,13 @@ into the Executor, which frees resources through the DB like any other job.
 
 from __future__ import annotations
 
-import json
 import threading
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
 
 from repro import configs
+from repro.launch.cache import configure_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
 from repro.train.loop import train_loop
 
@@ -34,6 +33,7 @@ class ClusterRunner:
     """Runs 'train' job specs on the local devices, one thread per job."""
 
     def __init__(self, db, executor, *, default_rules=None):
+        configure_compile_cache()
         self.db = db
         self.executor = executor
         self.rules = default_rules or shd.make_rules(multi_pod=False)
@@ -58,12 +58,12 @@ class ClusterRunner:
 
     def _run(self, spec: dict) -> None:
         job_id = spec["idJob"]
-        cfg = configs.get_smoke(spec.get("arch", "tiny")) \
-            if spec.get("smoke", True) else configs.get(spec["arch"])
-        cfg = cfg.replace(dtype="float32")
-        n = jax.device_count()
-        mesh = Mesh(np.array(jax.devices()).reshape(n, 1), ("data", "model"))
         try:
+            cfg = configs.get_smoke(spec.get("arch", "tiny")) \
+                if spec.get("smoke", True) else configs.get(spec["arch"])
+            if jax.default_backend() == "cpu":
+                cfg = cfg.replace(dtype="float32")
+            mesh = make_mesh(jax.devices(), (-1, 1))
             result = train_loop(
                 cfg, mesh, self.rules,
                 steps=spec.get("steps", 100),
@@ -82,10 +82,7 @@ class ClusterRunner:
             # the checkpoint makes the resubmitted clone resume.
         except Exception as exc:  # noqa: BLE001 — job failure, not ours
             self.results[job_id] = exc
-            try:
-                self.executor.complete(job_id, ok=False, message=repr(exc))
-            except Exception:
-                pass
+            self.executor.complete(job_id, ok=False, message=repr(exc))
 
     def wait_all(self, timeout: float = 300.0) -> None:
         for t in list(self.threads.values()):

@@ -1,4 +1,8 @@
-"""Mesh construction for the production topology.
+"""Mesh construction: :func:`make_mesh` is the one place a mesh is built.
+
+Every mesh carries Auto axis types, set explicitly: ``jax.make_mesh``
+defaults to Explicit axes, which ``with_sharding_constraint`` (the
+activation constraints of ``parallel/ctx.py``) refuses to name.
 
 ``make_production_mesh`` is a function (never a module-level constant) so
 importing this module does not touch jax device state. The dry-run sets
@@ -14,11 +18,13 @@ collectives stay inside a pod's ICI torus.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_local_mesh", "HW"]
+__all__ = ["make_mesh", "make_production_mesh", "HW"]
 
 
 # TPU v5e hardware constants (per chip), used by the roofline analysis.
@@ -30,15 +36,14 @@ HW = {
 }
 
 
+def make_mesh(devices, shape: tuple[int, ...],
+              axes: tuple[str, ...] = ("data", "model")) -> Mesh:
+    """``devices`` laid out as ``shape`` (one entry may be -1), Auto axes."""
+    devs = np.asarray(devices).reshape(shape)
+    return Mesh(devs, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(model_axis: int = 1) -> Mesh:
-    """Mesh over whatever devices exist (CPU smoke tests / tiny trainer)."""
-    n = jax.device_count()
-    assert n % model_axis == 0
-    devs = np.array(jax.devices()).reshape(n // model_axis, model_axis)
-    return Mesh(devs, ("data", "model"))
+    return make_mesh(jax.devices()[:math.prod(shape)], shape, axes)

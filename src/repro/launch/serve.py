@@ -17,9 +17,10 @@ import time
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
 
 from repro import configs
+from repro.launch.cache import configure_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.serve.engine import ServeEngine
@@ -38,11 +39,12 @@ def main(argv=None) -> None:
                     help="serving rule set (resident 2-D-sharded weights)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = configs.get(args.arch) if args.full else configs.get_smoke(args.arch)
     if jax.default_backend() == "cpu":
         cfg = cfg.replace(dtype="float32", use_pallas=False)
-    mesh = Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
+    mesh = make_mesh(jax.devices(), (-1, 1))
     rules = shd.make_rules(multi_pod=False, tp2d=args.tp2d)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     engine = ServeEngine(cfg, mesh, rules, params,

@@ -15,20 +15,13 @@ from __future__ import annotations
 import argparse
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
 
 from repro import configs
+from repro.launch.cache import configure_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
 from repro.train.loop import train_loop
 from repro.train.optimizer import OptConfig
-
-
-def make_local_mesh(model_parallel: int = 1) -> Mesh:
-    n = jax.device_count()
-    assert n % model_parallel == 0
-    devs = np.array(jax.devices()).reshape(n // model_parallel, model_parallel)
-    return Mesh(devs, ("data", "model"))
 
 
 def main(argv=None) -> None:
@@ -49,12 +42,13 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--fsdp", action="store_true")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = configs.get(args.arch) if (args.full or args.arch == "tiny") \
         else configs.get_smoke(args.arch)
     if jax.default_backend() == "cpu":
         cfg = cfg.replace(dtype="float32", use_pallas=False)
-    mesh = make_local_mesh(args.model_parallel)
+    mesh = make_mesh(jax.devices(), (-1, args.model_parallel))
     rules = shd.make_rules(multi_pod=False, fsdp=args.fsdp)
     print(f"arch={cfg.name} params={cfg.param_count():,} "
           f"mesh={dict(mesh.shape)} backend={jax.default_backend()}")

@@ -16,9 +16,8 @@ from typing import Callable
 import jax
 
 from repro.data.pipeline import data_iterator
-from repro.parallel import sharding as shd
 from repro.parallel.steps import (init_train_state, make_train_step,
-                                  abstract_train_state)
+                                  abstract_train_state, train_state_shardings)
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import OptConfig
 
@@ -51,6 +50,9 @@ def train_loop(cfg, mesh, rules, *, steps: int, global_batch: int,
             start = restored
     if state is None:
         state = init_train_state(cfg, jax.random.PRNGKey(seed))
+    # the step's outputs carry its shardings; a fresh or restored state
+    # placed the same way keeps every call on one compiled program
+    state = jax.device_put(state, train_state_shardings(cfg, mesh, rules))
 
     it = data_iterator(cfg, global_batch, seq_len, seed=seed, start_step=start)
     history, metrics = [], {}

@@ -1,4 +1,3 @@
-import importlib.util
 import os
 import sys
 
@@ -9,12 +8,11 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Suite split (markers registered in pytest.ini): the data-plane modules
-# exercise JAX/Pallas kernels and need the accelerator toolchain; everything
-# else is the stdlib-only control plane. `pytest -m "not data_plane"` is the
-# CI gate that must stay green — it cannot be drowned out by the known
-# data-plane failures on the reference container.
+# need JAX; everything else is the stdlib-only control plane, which
+# `pytest -m "not data_plane"` selects on runners without JAX.
 DATA_PLANE_MODULES = {"test_kernels", "test_kernels_smoke", "test_arch_smoke",
-                      "test_train_serve", "test_sharding_rules"}
+                      "test_train_serve", "test_sharding_rules",
+                      "test_tpu_compile"}
 
 
 def pytest_collection_modifyitems(items):
@@ -24,20 +22,6 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.data_plane)
         else:
             item.add_marker(pytest.mark.control_plane)
-
-# The property tests want hypothesis; the container may not ship it. Install
-# the minimal random-sampling shim in its place so the suite still collects
-# and the properties still get exercised (weaker generation, same asserts).
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_compat.py"))
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 # Toolchain-less runners (e.g. the GitHub control-plane job) have no JAX at
 # all: skip collecting the data-plane modules entirely — marker deselection

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.parallel import steps as steps_mod
@@ -30,7 +31,7 @@ def make_batch(cfg, rng, batch=B, seq=S):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh(jax.devices()[:1], (1, 1))
 
 
 @pytest.mark.parametrize("arch", configs.ARCHS)

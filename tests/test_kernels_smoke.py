@@ -2,8 +2,8 @@
 
 The CI-sized cousin of test_kernels.py: a single minimal parametrisation
 per kernel — enough to catch an import error, an API drift in the Pallas
-toolchain (e.g. the CompilerParams rename handled by kernels/compat.py) or
-a gross numerical break, in seconds instead of the full grid's minutes.
+toolchain or a gross numerical break, in seconds instead of the full
+grid's minutes.
 The exhaustive shape/dtype sweep stays out of the CI gate.
 """
 

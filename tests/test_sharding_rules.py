@@ -6,6 +6,7 @@ import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.models.layers import ParamSpec
 from repro.parallel import sharding as shd
 
@@ -14,9 +15,7 @@ from repro.parallel import sharding as shd
 def mesh():
     # 4 = 2×2 stand-in for (data, model); divisibility logic is identical
     devs = jax.devices() * 4  # replicate the single CPU device
-    import numpy as np
-    return jax.sharding.Mesh(np.array(devs[:4]).reshape(2, 2),
-                             ("data", "model"))
+    return make_mesh(devs[:4], (2, 2))
 
 
 def spec(shape, axes):
@@ -73,3 +72,25 @@ def test_mesh_axis_used_once_per_param(mesh):
             continue
         flat.extend(e if isinstance(e, tuple) else (e,))
     assert len(flat) == len(set(flat))
+
+
+@pytest.mark.parametrize("batch,lead", [(8, "data"), (6, None)])
+def test_constrain_logical_keeps_data_axis_on_batch(batch, lead):
+    """Under make_mesh's Auto axes the activation constraint traces, and on
+    a (4, 1) data-parallel mesh `data` lands on the batch dim wherever the
+    batch divides by 4 (else the dim stays replicated)."""
+    import jax.numpy as jnp
+    from repro.parallel.ctx import constrain_logical, sharding_ctx
+
+    mesh = make_mesh(jax.devices() * 4, (4, 1))
+    rules = shd.make_rules(multi_pod=False)
+
+    def f(x):
+        with sharding_ctx(mesh, rules):
+            return constrain_logical(x, ("batch", "seq", "act_embed"))
+
+    traced = jax.jit(f).trace(jax.ShapeDtypeStruct((batch, 16, 32),
+                                                   jnp.float32))
+    [eqn] = [e for e in traced.jaxpr.eqns
+             if e.primitive.name == "sharding_constraint"]
+    assert eqn.params["sharding"].spec == (P(lead) if lead else P())

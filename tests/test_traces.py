@@ -17,8 +17,8 @@ FIXTURE = os.path.join(REPO_ROOT, "benchmarks", "data", "mini_cluster.swf")
 KTH_FIXTURE = os.path.join(REPO_ROOT, "benchmarks", "data",
                            "kth_sp2_standin.swf")
 
-# shim-compatible field strategies (ints bounded well under 2**53 so the
-# float hop in the int-column parser stays exact)
+# field strategies (ints bounded well under 2**53 so the float hop in the
+# int-column parser stays exact)
 _int = st.integers(min_value=-1, max_value=1 << 40)
 _time = st.floats(min_value=-1.0, max_value=1e9,
                   allow_nan=False, allow_infinity=False)

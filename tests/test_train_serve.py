@@ -8,18 +8,18 @@ import pytest
 
 from repro import configs
 from repro.data.pipeline import data_iterator
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.parallel import sharding as shd
-from repro.parallel.steps import make_train_step, init_train_state
+from repro.parallel.steps import init_train_state
 from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as ckpt
 from repro.train.loop import train_loop
-from repro.train.optimizer import OptConfig
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh(jax.devices()[:1], (1, 1))
 
 
 CFG = configs.get_smoke("tiny").replace(dtype="float32")
@@ -71,6 +71,27 @@ def test_train_preemption_checkpoints(mesh, tmp_path):
     assert ckpt.latest_step(str(tmp_path)) == res.step
 
 
+def test_train_loop_compiles_its_step_once(mesh):
+    """A fresh state is placed on the step's shardings, so the first call and
+    every later one (fed the step's own outputs) run one compiled program."""
+    compiles = []
+
+    def listen(event, secs, **kw):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and kw.get("fun_name") == "jit(train_step)"):
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        with mesh:
+            res = train_loop(CFG, mesh, RULES, steps=3, global_batch=2,
+                             seq_len=16)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert res.step == 3
+    assert len(compiles) == 1
+
+
 def test_data_iterator_deterministic_and_resumable():
     a = data_iterator(CFG, 2, 16, seed=3)
     b = data_iterator(CFG, 2, 16, seed=3)
@@ -107,3 +128,29 @@ def test_serve_engine_completes_all_and_greedy_matches_reference(mesh):
     expect = int(jnp.argmax(logits, -1)[0])
     assert done[0].generated[0] == expect or any(
         r.prompt == prompts[0] and r.generated[0] == expect for r in done)
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placement(monkeypatch, env):
+    """An outside JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache goes to the fixed, git-ignored <repo>/.jax_cache."""
+    import pathlib
+
+    from repro.launch import cache
+
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = cache.configure_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env is not None:
+        assert (got, now) == (env, before)
+        return
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert got == now == str(repo / ".jax_cache")
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
