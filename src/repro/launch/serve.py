@@ -60,9 +60,11 @@ def main(argv=None) -> None:
         done = engine.run()
         dt = time.perf_counter() - t0
     total = sum(len(r.generated) for r in done)
+    c = engine.counters
     print(f"arch={cfg.name} served {len(done)} requests, {total} tokens in "
-          f"{engine.steps_run} steps ({dt:.1f}s)")
-    print(f"slot efficiency {total / (engine.steps_run * args.max_batch):.1%}")
+          f"{c.steps} steps ({dt:.1f}s)")
+    print(f"slot efficiency "
+          f"{c.slot_steps_active / (c.steps * args.max_batch):.1%}")
 
 
 if __name__ == "__main__":

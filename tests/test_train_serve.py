@@ -130,6 +130,45 @@ def test_serve_engine_completes_all_and_greedy_matches_reference(mesh):
         r.prompt == prompts[0] and r.generated[0] == expect for r in done)
 
 
+def test_serve_engine_stamps_counts_and_spans(mesh):
+    """Each request's stamps are in order, the counters add up to the
+    tokens served, and every span of a step's work lies inside that step."""
+    from repro.spans import Spans
+
+    params = M.init_params(CFG, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(2)
+    with Spans() as spans, mesh:
+        engine = ServeEngine(CFG, mesh, RULES, params, max_batch=2,
+                             max_len=32, spans=spans)
+        for i in range(5):
+            engine.submit(rng.integers(0, CFG.vocab_size, 4 + 4 * (i % 2))
+                          .tolist(), max_new_tokens=1 + i)
+        done = engine.run(max_steps=100)
+    assert all(r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+               for r in done)
+    c = engine.counters
+    assert c.decoded_tokens + c.prefills == sum(len(r.generated)
+                                                for r in done)
+    assert (c.prefills, c.prompt_tokens) == (5, sum(len(r.prompt)
+                                                    for r in done))
+    assert c.slot_steps_active == c.decoded_tokens
+    assert c.steps == engine.steps_run
+    recs = list(spans.records)
+    steps = [r for r in recs if r.name == "serve.step"]
+    inner = [r for r in recs if r.name in ("serve.sync", "serve.decode",
+                                           "serve.prefill")]
+    assert len(steps) >= c.steps and len(inner) >= 2 * c.steps + 2 * c.prefills
+    for r in inner:
+        assert any(s.t0 <= r.t0 <= r.t1 <= s.t1 for s in steps), r
+    for r in done:
+        mine = sorted(x.name for x in recs if x.attr == r.rid
+                      and x.name.startswith("serve."))
+        assert mine == ["serve.prefill", "serve.queue"]
+        queue = next(x for x in recs if x.attr == r.rid
+                     and x.name == "serve.queue")
+        assert (queue.t0, queue.t1) == (r.t_submit, r.t_admit)
+
+
 @pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
 def test_compile_cache_placement(monkeypatch, env):
     """An outside JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
