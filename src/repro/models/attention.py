@@ -2,9 +2,11 @@
 
 Layout convention: activations (B, S, D); projections keep heads explicit
 ((B, S, H, Dh)) so the `heads` logical axis shards over the mesh `model`
-axis without reshapes. KV caches are (B, Smax, K, Dh); sliding-window archs
-use a ring buffer of size ``window`` so a 500k-token decode holds a bounded
-cache (the systems point that makes `long_500k` runnable at all).
+axis without reshapes. KV caches are head-major, (B, K, Smax, Dh): the
+layout the decode's score and value dots read, so a layer's cache feeds them
+straight from a layer-stacked buffer, with no transposed copy. Sliding-window
+archs use a ring buffer of size ``window`` so a 500k-token decode holds a
+bounded cache (the systems point that makes `long_500k` runnable at all).
 """
 
 from __future__ import annotations
@@ -83,18 +85,23 @@ def cross_attn_apply(p: dict, x: jax.Array, memory, cfg):
 
 
 def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-                pos: jax.Array, cfg, *, window: int | None = None):
+                pos: jax.Array, cfg, *, window: int | None = None,
+                layer: jax.Array | None = None):
     """One-token decode step.
 
-    x: (B, 1, D); cache_k/v: (B, Smax, K, Dh); pos: (B,) int32 (absolute
+    x: (B, 1, D); cache_k/v: (B, K, Smax, Dh), or (L, B, K, Smax, Dh) stacked
+    over layers with ``layer`` this layer's index; pos: (B,) int32 (absolute
     position of each row's token — rows may differ under continuous
     batching). Sliding-window caches (Smax == window) are ring buffers
     indexed ``pos % Smax``; rope uses absolute positions so rotation is
-    consistent across wraps. Returns (out (B,1,D), cache_k, cache_v).
+    consistent across wraps. Only each row's new key and value are written,
+    at ``[layer,] row, :, pos % Smax``, so a donated cache is updated in place;
+    the attention then reads this layer's cache with the new rows in it.
+    Returns (out (B,1,D), cache_k, cache_v), the caches as passed in plus
+    those rows.
     """
     B, _, D = x.shape
-    Smax = cache_k.shape[1]
-    K = cache_k.shape[2]
+    K, Smax = cache_k.shape[-3], cache_k.shape[-2]
     H, Dh = cfg.num_heads, cfg.head_dim
     G = H // K
     pos = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
@@ -105,14 +112,20 @@ def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     k_new = apply_rope(k_new, sin, cos)
 
     slot = (pos % Smax).astype(jnp.int32)                 # (B,)
-    rows = jnp.arange(B)
-    cache_k = cache_k.at[rows, slot].set(k_new[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[rows, slot].set(v_new[:, 0].astype(cache_v.dtype))
+    # one index per (row, head): each write is one (Dh,) vector, a form the
+    # TPU scatters without changing the cache's layout
+    at = (jnp.arange(B)[:, None], jnp.arange(K)[None, :], slot[:, None])
+    if layer is not None:
+        at = (layer, *at)
+    cache_k = cache_k.at[at].set(k_new[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[at].set(v_new[:, 0].astype(cache_v.dtype))
+    layer_k = cache_k if layer is None else cache_k[layer]
+    layer_v = cache_v if layer is None else cache_v[layer]
 
     qf = q.astype(jnp.float32).reshape(B, K, G, Dh)
-    kf = cache_k.astype(jnp.float32)
-    vf = cache_v.astype(jnp.float32)
-    s = jnp.einsum("bkgd,btkd->bkgt", qf, kf) * (Dh ** -0.5)
+    kf = layer_k.astype(jnp.float32)
+    vf = layer_v.astype(jnp.float32)
+    s = jnp.einsum("bkgd,bktd->bkgt", qf, kf) * (Dh ** -0.5)
     # slot j holds the token `age = (slot - j) mod Smax` steps in the past
     idx = jnp.arange(Smax)[None, :]
     age = (slot[:, None] - idx) % Smax                    # (B, Smax); 0 = now
@@ -121,6 +134,6 @@ def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         valid &= age < window
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     pattn = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", pattn, vf).reshape(B, 1, H, Dh)
+    o = jnp.einsum("bkgt,bktd->bkgd", pattn, vf).reshape(B, 1, H, Dh)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), p["wo"].astype(x.dtype))
     return out, cache_k, cache_v

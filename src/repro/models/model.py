@@ -172,7 +172,7 @@ def _layer_cache_shapes(cfg, kind: str, batch: int, max_len: int, dtype):
     slots = max_len
     if kind == "local_attn" or cfg.attention == "swa":
         slots = min(cfg.window, max_len)
-    c = {"k": ((batch, slots, K, Dh), dtype), "v": ((batch, slots, K, Dh), dtype)}
+    c = {"k": ((batch, K, slots, Dh), dtype), "v": ((batch, K, slots, Dh), dtype)}
     if kind == "cross":
         F = cfg.frontend_tokens
         c["enc_k"] = ((batch, F, K, Dh), dtype)
@@ -220,12 +220,18 @@ def decode_step(params, cfg, cache, tokens, pos):
     if _uniform_scan(cfg):
         kind = kinds[0]
 
-        def body(h, layer):
-            layer_p, layer_c = layer
-            h, new_c = tfm.block_decode(layer_p, h, layer_c, pos, cfg, kind)
-            return h, new_c
+        # the stacked cache rides in the carry, so each layer's row writes
+        # land in the donated buffer in place (passed as scan xs/ys, every
+        # layer's whole cache is sliced out, written back and copied)
+        def body(carry, layer):
+            h, c = carry
+            layer_p, i = layer
+            h, c = tfm.block_decode(layer_p, h, c, pos, cfg, kind, layer=i)
+            return (h, c), None
 
-        x, new_layers = jax.lax.scan(body, x, (params["layers"], layers_c))
+        (x, new_layers), _ = jax.lax.scan(
+            body, (x, layers_c),
+            (params["layers"], jnp.arange(cfg.num_layers)))
     else:
         new_layers = {}
         for i, kind in enumerate(kinds):

@@ -145,15 +145,16 @@ def block_prefill(p: dict, x: jax.Array, cfg, kind: str, max_len: int, *,
 
 
 def _kv_to_cache(k: jax.Array, v: jax.Array, slots: int) -> dict:
-    """Lay the prefill K/V into a ring/flat cache of ``slots`` positions."""
-    B, S, K, Dh = k.shape
+    """Lay the prefill K/V (B, S, K, Dh) into a head-major ring/flat cache of
+    ``slots`` positions, (B, K, slots, Dh)."""
+    k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+    S = k.shape[2]
     if S >= slots:   # keep the last `slots` positions; ring phase = S % slots
-        k_tail, v_tail = k[:, -slots:], v[:, -slots:]
         shift = (S % slots)
-        k_c = jnp.roll(k_tail, shift, axis=1)
-        v_c = jnp.roll(v_tail, shift, axis=1)
+        k_c = jnp.roll(k[:, :, -slots:], shift, axis=2)
+        v_c = jnp.roll(v[:, :, -slots:], shift, axis=2)
     else:
-        pad = ((0, 0), (0, slots - S), (0, 0), (0, 0))
+        pad = ((0, 0), (0, 0), (0, slots - S), (0, 0))
         k_c, v_c = jnp.pad(k, pad), jnp.pad(v, pad)
     return {"k": k_c, "v": v_c}
 
@@ -202,25 +203,34 @@ def _rglru_prefill(p, h, cfg):
 
 # ------------------------------------------------------------------- decode
 def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
-                 kind: str):
-    """One-token step. x: (B, 1, D). Returns (x, new_cache)."""
+                 kind: str, layer: jax.Array | None = None):
+    """One-token step. x: (B, 1, D). ``cache`` holds this layer's leaves or,
+    with ``layer`` this layer's index, every layer's leaves stacked on a
+    leading axis. Each leaf is written as little as its kind needs:
+    attention k/v one row per batch row, a recurrent state whole (it is
+    O(B x state)), cross-attention memory not at all. Returns (x, cache)."""
+    def read(c):
+        return c if layer is None else c[layer]
+
+    def write(c, new):
+        return new if layer is None else c.at[layer].set(new)
+
     h = rms_norm(x, p["pre_norm"], cfg.norm_eps)
-    if kind == "ssm":
-        out, new_cache = ssm_mod.ssm_decode(p, h, cache, cfg)
-        return x + out, new_cache
-    if kind == "rglru":
-        out, new_cache = rglru_mod.rglru_decode(p, h, cache, cfg)
+    if kind in ("ssm", "rglru"):
+        step = ssm_mod.ssm_decode if kind == "ssm" else rglru_mod.rglru_decode
+        out, new = step(p, h, {k: read(c) for k, c in cache.items()}, cfg)
         x = x + out
-        x, _ = _ffn(p, x, cfg, kind)
-        return x, new_cache
+        if kind == "rglru":
+            x, _ = _ffn(p, x, cfg, kind)
+        return x, {k: write(cache[k], new[k]) for k in cache}
     window = _window_for(cfg, kind)
     out, ck, cv = attn.attn_decode(p, h, cache["k"], cache["v"], pos, cfg,
-                                   window=window)
+                                   window=window, layer=layer)
     x = x + out
     new_cache = {**cache, "k": ck, "v": cv}
     if kind == "cross":
         hc = rms_norm(x, p["cross_norm"], cfg.norm_eps)
-        x = x + attn.cross_attn_apply(p["cross"], hc,
-                                      (cache["enc_k"], cache["enc_v"]), cfg)
+        x = x + attn.cross_attn_apply(
+            p["cross"], hc, (read(cache["enc_k"]), read(cache["enc_v"])), cfg)
     x, _ = _ffn(p, x, cfg, kind)
     return x, new_cache

@@ -169,11 +169,14 @@ def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
     dp = tuple(rules["batch"])
     dp_entry = dp if len(dp) > 1 else (dp[0] if dp else None)
 
-    def one(sd):
+    def one(path, sd):
         shape, _ = sd
         # layer-stacked caches: (L, B, ...) ; unstacked: (B, ...)
         entries = [None] * len(shape)
         bdim = 1 if len(shape) >= 2 and shape[0] == cfg.num_layers else 0
+        # attention k/v are head-major (B, K, slots, Dh); the other leaves
+        # put a sequence-like dim first (B, F|W-1, ...)
+        head_major = path[-1].key in ("k", "v")
         dp_n = 1
         for a in dp:
             dp_n *= mesh.shape[a]
@@ -182,7 +185,7 @@ def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
         # shard kv-heads/state heads over model when divisible…
         model_n = mesh.shape["model"]
         placed = False
-        for i in range(bdim + 2, len(shape)):
+        for i in range(bdim + (1 if head_major else 2), len(shape)):
             if shape[i] in (cfg.num_kv_heads, cfg.ssm_heads) and \
                     shape[i] % model_n == 0:
                 entries[i] = "model"
@@ -192,18 +195,14 @@ def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
         # standard sequence-sharded KV cache — keeps a 32k×128-row cache
         # at ~2.5 GB/chip instead of 40 GB/chip)
         if not placed and len(shape) >= bdim + 3:
-            slots_dim = bdim + 1
+            slots_dim = bdim + (2 if head_major else 1)
             if shape[slots_dim] % model_n == 0:
                 entries[slots_dim] = "model"
         while entries and entries[-1] is None:
             entries.pop()
         return P(*entries)
 
-    return jax.tree_util.tree_map(
+    return jax.tree_util.tree_map_with_path(
         one, cache_shape_tree,
         is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
         and isinstance(x[0], tuple))
-
-
-def constrain(x, mesh: Mesh, pspec: P):
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))

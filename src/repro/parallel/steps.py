@@ -7,7 +7,9 @@ ShapeDtypeStruct inputs; smoke tests and the tiny trainer execute them.
 Distributed-optimization features:
   * microbatch gradient accumulation (``lax.scan`` over the leading
     microbatch dim — keeps peak activation memory at 1/M),
-  * donated state/cache buffers (in-place update, no double allocation),
+  * donated state/cache buffers, updated in place: the decode's layer scan
+    carries the stacked cache and writes only each row's new key and value
+    (``models.model.decode_step``), so no step copies the cache,
   * activation sharding constraints via repro.parallel.ctx,
   * rematerialised layer stacks (cfg.remat) — compute/comm overlap then
     falls out of XLA's latency-hiding scheduler on real hardware.

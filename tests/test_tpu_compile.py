@@ -4,13 +4,14 @@ Nothing runs: each program is lowered against shapes placed on a device of
 a described (not attached) ``v5e:2x2`` topology and compiled by the TPU
 compiler that ships with JAX. This catches what interpret mode cannot —
 block shapes off the (8, 128) tiling, ops Mosaic does not lower, programs
-that overflow the chip's 16 GiB — at the widths the chip runs
-(``chip_smoke.py``). The topology is described inside a fixture, never at
-import, so every xdist worker collects the same tests and only the worker
-given this file loads the TPU library.
+that overflow the chip's 16 GiB, a decode step that copies its KV cache —
+at the widths the chip runs (``chip_smoke.py``). The topology is described
+inside a fixture, never at import, so every xdist worker collects the same
+tests and only the worker given this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +23,10 @@ from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.rglru.kernel import lru_scan_kernel
 from repro.kernels.ssd.kernel import ssd_kernel
 from repro.launch.mesh import make_mesh
+from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.parallel.steps import (abstract_batch, abstract_train_state,
-                                  make_train_step)
+                                  make_serve_step, make_train_step)
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 
@@ -95,3 +97,51 @@ def test_mamba2_130m_train_step_fits_one_v5e(topo):
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert cfg.param_count() * 12 <= m.argument_size_in_bytes  # f32 p, mu, nu
     assert total < HBM_BYTES, total / 2**30
+
+
+# Published widths with 4 stacked layers, at the granite-8b.serve_chat cell's
+# 32 rows of 4096 positions: at smaller shapes the compiler may keep a cache
+# in on-chip memory, or fuse a whole-layer copy it makes at served sizes, so
+# a guard there would pass what the chip pays for. mixtral keeps 2 of its 8
+# experts so the stage fits one chip, and a 1024-slot window so its cache is
+# a ring buffer shorter than max_len.
+DECODE_CFGS = {
+    "granite-8b": dict(num_layers=4),
+    "mixtral-8x22b": dict(num_layers=4, num_experts=2, window=1024),
+    "mamba2-130m": dict(num_layers=4),
+}
+_INSTR = re.compile(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE_CFGS))
+def test_decode_updates_cache_in_place(topo, arch):
+    """The decode step writes its rows into the donated cache: no copy of
+    a stacked cache leaf, no whole-layer write of an attention cache, and
+    no temporary as large as one layer of the largest leaf."""
+    cfg = configs.get(arch).replace(**DECODE_CFGS[arch])
+    B, max_len = 32, 4096
+    step = make_serve_step(cfg, make_mesh(topo.devices[:1], (1, 1)),
+                           shd.make_rules(multi_pod=False),
+                           global_batch=B, max_len=max_len)
+    cache = M.abstract_cache(cfg, B, max_len)
+    compiled = step.lower(
+        M.abstract_params(cfg, jnp.bfloat16), cache,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32)).compile()
+
+    leaves = jax.tree_util.tree_leaves_with_path(cache)
+    stacked = {leaf.shape for _, leaf in leaves}
+    # attention k/v change one row per batch row; a recurrent state is
+    # rewritten whole, so its one-layer dynamic-update-slice is the write
+    rows = {leaf.shape for path, leaf in leaves if path[-1].key in ("k", "v")}
+    ops = {(op, tuple(int(d) for d in dims.split(",") if d))
+           for dims, op in _INSTR.findall(compiled.as_text())}
+    assert not {(op, s) for op, s in ops
+                if op in ("copy", "copy-start") and s in stacked}
+    assert not {(op, s) for op, s in ops
+                if op == "dynamic-update-slice" and s in rows}
+
+    m = compiled.memory_analysis()
+    nbytes = [leaf.size * leaf.dtype.itemsize for _, leaf in leaves]
+    assert m.alias_size_in_bytes == sum(nbytes)          # donated, reused
+    assert m.temp_size_in_bytes < max(nbytes) // cfg.num_layers
