@@ -35,11 +35,23 @@ class ModelConfig:
     attn_q_block: int = 1024        # chunked-attention tile sizes; carry
     attn_k_block: int = 1024        # traffic ∝ S/attn_k_block per q tile
 
+    # latent attention (MLA, DeepSeek-V2/V3): keys and values expand from
+    # one normed latent of kv_lora_rank per token, with a rope part of
+    # qk_rope_head_dim shared by every head; the decode caches the latent
+    kv_lora_rank: int = 0           # 0 → no latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # MoE
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    num_shared_experts: int = 0     # always-on SwiGLU of that many × moe_d_ff
+    first_dense_layers: int = 0     # leading layers with a dense d_ff MLP
+    router: str = "softmax"         # softmax | sigmoid (choice bias, noaux_tc)
+    routed_scaling: float = 1.0     # gates × this after their normalisation
+    capacity_factor: float = 1.25   # training dispatch only; serving drops none
     router_aux_coef: float = 0.01
 
     # SSM (mamba2 / SSD)

@@ -33,8 +33,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   scale: float | None = None, q_offset: int = 0) -> jax.Array:
     """Grouped-query attention.
 
-    q: (B, Sq, H, D);  k, v: (B, Sk, K, D) with H % K == 0.
-    Returns (B, Sq, H, D) in q.dtype; softmax in float32.
+    q, k: (B, Sq|Sk, H|K, D); v: (B, Sk, K, Dv) with H % K == 0.
+    Returns (B, Sq, H, Dv) in q.dtype; softmax in float32.
     """
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
@@ -49,7 +49,7 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     s = jnp.where(m[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, vf)
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -109,13 +109,13 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 "bkgqs,bskd->bkgqd", p, vb.astype(jnp.float32))
             return (acc_new, m_new, l_new), None
 
-        init = (jnp.zeros((B, K, G, q_block, D), jnp.float32),
+        init = (jnp.zeros((B, K, G, q_block, v.shape[-1]), jnp.float32),
                 jnp.full((B, K, G, q_block), -jnp.inf, jnp.float32),
                 jnp.zeros((B, K, G, q_block), jnp.float32))
         (acc, m, l), _ = jax.lax.scan(jax.checkpoint(body), init,
                                       jnp.arange(nk))
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return jnp.moveaxis(out, 3, 1).reshape(B, q_block, H, D)
+        return jnp.moveaxis(out, 3, 1).reshape(B, q_block, H, v.shape[-1])
 
     outs = []
     for i in range(nq):

@@ -7,9 +7,19 @@ layout the decode's score and value dots read, so a layer's cache feeds them
 straight from a layer-stacked buffer, with no transposed copy. Sliding-window
 archs use a ring buffer of size ``window`` so a 500k-token decode holds a
 bounded cache (the systems point that makes `long_500k` runnable at all).
+
+Latent attention (MLA) caches one normed latent row per token, shared by
+every head: (B, Smax, kv_lora_rank + qk_rope_head_dim), the latent followed
+by the roped key part. Its prefill expands the latent to per-head keys and
+values and runs the usual attention; its decode never expands the cache:
+the key up-projection is absorbed into the query and the value
+up-projection into the output, so the scores and the weighted sum read the
+latent rows directly.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +27,8 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention
 from repro.models.layers import ParamSpec, apply_rope, rms_norm, rotary_embedding
 
-__all__ = ["attn_specs", "attn_apply", "attn_decode", "cross_attn_apply"]
+__all__ = ["attn_specs", "attn_apply", "attn_decode", "cross_attn_apply",
+           "mla_specs", "mla_apply", "mla_decode"]
 
 
 def attn_specs(cfg, *, cross: bool = False) -> dict:
@@ -137,3 +148,116 @@ def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     o = jnp.einsum("bkgt,bktd->bkgd", pattn, vf).reshape(B, 1, H, Dh)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), p["wo"].astype(x.dtype))
     return out, cache_k, cache_v
+
+
+# ------------------------------------------------------- latent attention
+def mla_specs(cfg) -> dict:
+    D, H = cfg.d_model, cfg.num_heads
+    N, R, C = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    V = cfg.v_head_dim
+    return {
+        "wq": ParamSpec((D, H, N + R), ("embed", "heads", "head")),
+        "wkv_a": ParamSpec((D, C + R), ("embed", None)),
+        "kv_norm": ParamSpec((C,), (None,), init="ones"),
+        "wk_b": ParamSpec((C, H, N), (None, "heads", "head")),
+        "wv_b": ParamSpec((C, H, V), (None, "heads", "head")),
+        "wo": ParamSpec((H, V, D), ("heads", "head", "embed"),
+                        fan_in_axes=(0, 1)),
+    }
+
+
+def _rope_pairs(x, positions, cfg):
+    """Rotary embedding of adjacent pairs (2i, 2i+1) at frequency i, as the
+    published MLA models apply it: the pairs are gathered into halves, then
+    rotated. x: (B, S, heads, R); positions: (B, S)."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    sin, cos = rotary_embedding(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    return apply_rope(x, sin, cos)
+
+
+def _mla_project(p, x, positions, cfg):
+    """(q_nope (B,S,H,N), q_pe (B,S,H,R) roped, the latent row (B,S,C+R):
+    the normed latent then the roped key part)."""
+    N, C = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    kv = jnp.einsum("bsd,dc->bsc", x, p["wkv_a"].astype(x.dtype))
+    c = rms_norm(kv[..., :C], p["kv_norm"], cfg.norm_eps)
+    k_pe = _rope_pairs(kv[..., None, C:], positions, cfg)[..., 0, :]
+    return q[..., :N], _rope_pairs(q[..., N:], positions, cfg), \
+        jnp.concatenate([c, k_pe], axis=-1)
+
+
+def mla_apply(p: dict, x: jax.Array, cfg):
+    """Full-sequence causal latent attention (train / prefill), with the
+    latent expanded to per-head keys (N + R wide) and values (V wide).
+    x: (B, S, D). Returns (out (B, S, D), latent rows (B, S, C + R))."""
+    B, S, _ = x.shape
+    H, C = cfg.num_heads, cfg.kv_lora_rank
+    with jax.named_scope("mla.latent"):
+        q_nope, q_pe, row = _mla_project(p, x, jnp.arange(S)[None, :], cfg)
+        c = row[..., :C]
+        k_nope = jnp.einsum("bsc,chn->bshn", c, p["wk_b"].astype(x.dtype))
+        v = jnp.einsum("bsc,chv->bshv", c, p["wv_b"].astype(x.dtype))
+        k_pe = jnp.broadcast_to(row[:, :, None, C:],
+                                (B, S, H, cfg.qk_rope_head_dim))
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate([k_nope, k_pe], axis=-1)
+    with jax.named_scope("mla.attend"):
+        o = flash_attention(q, k, v, causal=True, use_pallas=cfg.use_pallas,
+                            chunked=cfg.attn_chunked,
+                            q_chunk=cfg.attn_q_block,
+                            k_chunk=cfg.attn_k_block)
+        out = jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(x.dtype))
+    return out, row
+
+
+def mla_decode(p: dict, x: jax.Array, cache: jax.Array, pos: jax.Array, cfg,
+               *, layer: jax.Array | None = None):
+    """One-token latent-attention step over the latent cache, absorbed.
+
+    x: (B, 1, D); cache: (B, Smax, C + R), or (L, B, Smax, C + R) stacked
+    over layers with ``layer`` this layer's index; pos: (B,) int32. Each
+    row's new latent row is written at ``[layer,] row, pos % Smax`` (in
+    place in a donated cache). The scores are ``q_nope·W_kb`` (per head,
+    C wide) against the latent plus ``q_pe`` against the roped key part;
+    the weighted sum of latent rows goes through ``W_vb`` per head, then
+    ``wo``. The dots take the cache's dtype with float32 sums. Returns
+    (out (B, 1, D), cache).
+    """
+    B = x.shape[0]
+    C = cfg.kv_lora_rank
+    Smax = cache.shape[-2]
+    pos = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
+    with jax.named_scope("mla.latent"):
+        q_nope, q_pe, row = _mla_project(p, x, pos[:, None], cfg)
+        slot = (pos % Smax).astype(jnp.int32)
+        # written as (C + R) / w pieces of w: a whole 576-wide row per index
+        # makes the TPU compiler relayout the whole cache around the scatter
+        w = math.gcd(C, cfg.qk_rope_head_dim)
+        pieces = cache.reshape(*cache.shape[:-1], -1, w)
+        n = pieces.shape[-2]
+        at = (jnp.arange(B)[:, None], slot[:, None], jnp.arange(n)[None, :])
+        if layer is not None:
+            at = (layer, *at)
+        pieces = pieces.at[at].set(row[:, 0].reshape(B, n, w).astype(cache.dtype))
+        cache = pieces.reshape(cache.shape)
+        lat = cache if layer is None else cache[layer]
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
+                           p["wk_b"].astype(x.dtype))
+        qc = jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1).astype(lat.dtype)
+    with jax.named_scope("mla.attend"):
+        scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+        s = jnp.einsum("bhc,btc->bht", qc, lat,
+                       preferred_element_type=jnp.float32) * scale
+        age = (slot[:, None] - jnp.arange(Smax)[None, :]) % Smax
+        valid = age <= jnp.minimum(pos, Smax - 1)[:, None]
+        s = jnp.where(valid[:, None, :], s, -1e30)
+        pattn = jax.nn.softmax(s, axis=-1).astype(lat.dtype)
+        # the whole row, rope part included, so no C-wide slice of the
+        # cache is copied; the rope part of the sum is dropped after
+        o_lat = jnp.einsum("bht,btc->bhc", pattn, lat,
+                           preferred_element_type=jnp.float32)[..., :C]
+        o = jnp.einsum("bhc,chv->bhv", o_lat.astype(x.dtype),
+                       p["wv_b"].astype(x.dtype))
+        out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(x.dtype))
+    return out[:, None], cache

@@ -3,6 +3,12 @@ every assigned architecture family.
 
 Scan-over-layers is the default (depth-independent HLO ⇒ fast compiles and
 bounded dry-run cost); hybrids with a non-uniform layer pattern unroll.
+A mixture-of-experts model's leading dense layers (``first_dense_layers``)
+form a group of their own, ``dense_layers``, stacked like ``layers`` but
+applied unrolled ahead of the scanned MoE layers. In the params and the
+cache a group is either stacked ({group: {leaf: (L, ...)}}) or holds one
+dict per layer ({group: {"layer_i": {leaf: ...}}}); :func:`stacked` tells
+a cache's apart by that structure.
 All public functions treat ``cfg`` as static (hashable frozen dataclass).
 """
 
@@ -14,6 +20,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.models.layers import (ParamSpec, abstract_tree, init_tree,
                                  rms_norm, take_embedding)
@@ -23,16 +30,32 @@ from repro.models.rglru import rglru_cache_shapes
 
 __all__ = ["param_shapes", "init_params", "abstract_params", "forward",
            "loss_fn", "cache_shapes", "init_cache", "abstract_cache",
-           "decode_step", "prefill", "compute_dtype"]
+           "decode_step", "prefill", "compute_dtype", "stacked"]
 
 
 def compute_dtype(cfg):
     return jnp.dtype(cfg.dtype)
 
 
+def _lead(cfg) -> int:
+    """Leading layers in the ``dense_layers`` group (scanned stacks only)."""
+    return cfg.first_dense_layers if cfg.scan_layers else 0
+
+
 def _uniform_scan(cfg) -> bool:
-    kinds = tfm.layer_kinds(cfg)
+    kinds = tfm.layer_kinds(cfg)[_lead(cfg):]
     return cfg.scan_layers and len(set(kinds)) == 1
+
+
+def stacked(path) -> bool:
+    """Is the decode-cache leaf at ``path`` stacked over its group's
+    layers? A stacked leaf sits directly in its group; an unrolled group
+    holds one dict per layer."""
+    return len(path) == 2
+
+
+def _layer(tree, i: int):
+    return jax.tree_util.tree_map(lambda a: a[i], tree)
 
 
 # --------------------------------------------------------------------- specs
@@ -46,10 +69,14 @@ def param_shapes(cfg) -> dict:
     if not cfg.tie_embeddings:
         specs["unembed"] = ParamSpec((D, V), ("embed", "vocab"))
     if _uniform_scan(cfg):
-        block = tfm.block_specs(cfg, kinds[0])
-        specs["layers"] = jax.tree_util.tree_map(
-            lambda s: s.with_prefix(cfg.num_layers), block,
-            is_leaf=lambda x: isinstance(x, ParamSpec))
+        lead = _lead(cfg)
+        groups = {"dense_layers": (kinds[0], lead),
+                  "layers": (kinds[-1], cfg.num_layers - lead)}
+        for group, (kind, n) in groups.items():
+            if n:
+                specs[group] = jax.tree_util.tree_map(
+                    lambda s: s.with_prefix(n), tfm.block_specs(cfg, kind),
+                    is_leaf=lambda x: isinstance(x, ParamSpec))
     else:
         specs["layers"] = {f"layer_{i}": tfm.block_specs(cfg, k)
                            for i, k in enumerate(kinds)}
@@ -73,10 +100,14 @@ def abstract_params(cfg, dtype=jnp.float32):
 
 
 # -------------------------------------------------------------------- trunk
-def _stack_apply(layers_p, x, cfg, kinds, *, memory=None):
+def _stack_apply(params, x, cfg, kinds, *, memory=None):
     """Run the layer stack. Returns (x, aux)."""
+    layers_p = params["layers"]
     if _uniform_scan(cfg):
-        kind = kinds[0]
+        for i in range(_lead(cfg)):
+            x, _ = tfm.block_apply(_layer(params["dense_layers"], i), x, cfg,
+                                   kinds[i])
+        kind = kinds[-1]
 
         def body(carry, layer_p):
             h, aux = carry
@@ -139,7 +170,7 @@ def forward(params, cfg, batch) -> tuple[jax.Array, jax.Array]:
     if cfg.is_encdec:
         memory = _encoder_apply(params, cfg,
                                 batch["audio_embeds"].astype(x.dtype))
-    x, aux = _stack_apply(params["layers"], x, cfg, kinds, memory=memory)
+    x, aux = _stack_apply(params, x, cfg, kinds, memory=memory)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _unembed(params, cfg, x), aux
 
@@ -169,6 +200,9 @@ def _layer_cache_shapes(cfg, kind: str, batch: int, max_len: int, dtype):
         return ssm_cache_shapes(cfg, batch, dtype)
     if kind == "rglru":
         return rglru_cache_shapes(cfg, batch, dtype)
+    if cfg.kv_lora_rank:        # one latent row a token, no head axis
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        return {"latent": ((batch, max_len, width), dtype)}
     slots = max_len
     if kind == "local_attn" or cfg.attention == "swa":
         slots = min(cfg.window, max_len)
@@ -185,9 +219,15 @@ def cache_shapes(cfg, batch: int, max_len: int, dtype=None) -> dict:
     dtype = compute_dtype(cfg) if dtype is None else dtype
     kinds = tfm.layer_kinds(cfg)
     if _uniform_scan(cfg):
-        per = _layer_cache_shapes(cfg, kinds[0], batch, max_len, dtype)
-        return {"layers": {k: ((cfg.num_layers, *shape), dt)
-                           for k, (shape, dt) in per.items()}}
+        lead = _lead(cfg)
+        out = {}
+        for group, kind, n in (("dense_layers", kinds[0], lead),
+                               ("layers", kinds[-1], cfg.num_layers - lead)):
+            if n:
+                per = _layer_cache_shapes(cfg, kind, batch, max_len, dtype)
+                out[group] = {k: ((n, *shape), dt)
+                              for k, (shape, dt) in per.items()}
+        return out
     return {"layers": {f"layer_{i}": _layer_cache_shapes(cfg, k, batch,
                                                          max_len, dtype)
                        for i, k in enumerate(kinds)}}
@@ -217,8 +257,16 @@ def decode_step(params, cfg, cache, tokens, pos):
     dt = compute_dtype(cfg)
     x = take_embedding(params["embed"], tokens, dt)
     layers_c = cache["layers"]
+    out = {}
     if _uniform_scan(cfg):
-        kind = kinds[0]
+        # leading layers unrolled, each writing its rows into its stack
+        for i in range(_lead(cfg)):
+            x, out["dense_layers"] = tfm.block_decode(
+                _layer(params["dense_layers"], i), x,
+                out.get("dense_layers", cache.get("dense_layers")), pos, cfg,
+                kinds[i], layer=i)
+        kind = kinds[-1]
+        sliced, whole = moe_mod.scan_split(params["layers"])
 
         # the stacked cache rides in the carry, so each layer's row writes
         # land in the donated buffer in place (passed as scan xs/ys, every
@@ -226,21 +274,22 @@ def decode_step(params, cfg, cache, tokens, pos):
         def body(carry, layer):
             h, c = carry
             layer_p, i = layer
-            h, c = tfm.block_decode(layer_p, h, c, pos, cfg, kind, layer=i)
+            h, c = tfm.block_decode({**layer_p, **whole}, h, c, pos, cfg,
+                                    kind, layer=i)
             return (h, c), None
 
-        (x, new_layers), _ = jax.lax.scan(
+        (x, out["layers"]), _ = jax.lax.scan(
             body, (x, layers_c),
-            (params["layers"], jnp.arange(cfg.num_layers)))
+            (sliced, jnp.arange(cfg.num_layers - _lead(cfg))))
     else:
-        new_layers = {}
+        out["layers"] = {}
         for i, kind in enumerate(kinds):
-            x, new_layers[f"layer_{i}"] = tfm.block_decode(
+            x, out["layers"][f"layer_{i}"] = tfm.block_decode(
                 params["layers"][f"layer_{i}"], x, layers_c[f"layer_{i}"],
                 pos, cfg, kind)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x)[:, 0]
-    return logits, {"layers": new_layers}
+    return logits, out
 
 
 # ------------------------------------------------------------------ prefill
@@ -257,20 +306,33 @@ def prefill(params, cfg, batch, max_len: int):
         memory = _encoder_apply(params, cfg,
                                 batch["audio_embeds"].astype(x.dtype))
     layers_p = params["layers"]
+    out = {}
     if _uniform_scan(cfg):
-        kind = kinds[0]
+        lead = []
+        for i in range(_lead(cfg)):
+            x, c, _ = tfm.block_prefill(_layer(params["dense_layers"], i), x,
+                                        cfg, kinds[i], max_len, memory=memory)
+            lead.append(c)
+        if lead:
+            out["dense_layers"] = jax.tree_util.tree_map(
+                lambda *cs: jnp.stack(cs), *lead)
+        kind = kinds[-1]
+        sliced, whole = moe_mod.scan_split(layers_p)
 
-        def body(h, layer_p):
-            h, layer_cache, _ = tfm.block_prefill(layer_p, h, cfg, kind,
-                                                  max_len, memory=memory)
+        def body(h, layer):
+            layer_p, i = layer
+            h, layer_cache, _ = tfm.block_prefill(
+                {**layer_p, **whole}, h, cfg, kind, max_len, memory=memory,
+                layer=i)
             return h, layer_cache
 
-        x, caches = jax.lax.scan(body, x, layers_p)
+        x, out["layers"] = jax.lax.scan(
+            body, x, (sliced, jnp.arange(cfg.num_layers - _lead(cfg))))
     else:
-        caches = {}
+        out["layers"] = {}
         for i, kind in enumerate(kinds):
-            x, caches[f"layer_{i}"], _ = tfm.block_prefill(
+            x, out["layers"][f"layer_{i}"], _ = tfm.block_prefill(
                 layers_p[f"layer_{i}"], x, cfg, kind, max_len, memory=memory)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x[:, -1:])[:, 0]
-    return logits, {"layers": caches}
+    return logits, out

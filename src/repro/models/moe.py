@@ -1,13 +1,30 @@
-"""Mixture-of-Experts layer: top-k routing with capacity-based dense dispatch.
+"""Mixture-of-Experts layer: top-k routing, a dropless grouped path for
+serving and a capacity dispatch for training.
 
-Expert-parallel by construction: expert weight tensors carry the `experts`
-logical axis (→ mesh `model` axis), and the dispatch/combine einsums lower
-to the all-to-all pattern under pjit. Capacity dispatch (tokens above
-capacity are dropped, MaxText-style) keeps every shape static for SPMD.
+Routing is softmax over the experts (Mixtral) or, with ``router ==
+"sigmoid"``, DeepSeek-V3's: sigmoid scores in float32, the top k chosen on
+the score plus a per-expert choice bias (``router_bias``,
+``e_score_correction_bias``), gated by the unbiased scores of the chosen
+experts. Either way the gates are normalised to sum to one and scaled by
+``routed_scaling``. Shared experts (``num_shared_experts``) are one SwiGLU
+of that many times ``moe_d_ff`` that every token passes through.
 
-The router aux (load-balancing) loss follows Switch/Mixtral:
-``E · Σ_e f_e · p_e`` with f the dispatch fraction and p the mean router
-probability per expert.
+Serving (prefill and decode, :func:`moe_serve`) is dropless: each token's
+k assignments are sorted by expert and run through grouped matrix products
+(``jax.lax.ragged_dot``), so the work is that of the assignments and each
+expert's weights are read once per call, whatever the batch. Inside a layer
+scan the expert weights stay stacked over the layers and the layer's
+experts are groups among all layers' (the others empty): a grouped product
+copies an operand that is a slice, so slicing out a layer's experts would
+copy them whole on every call.
+
+Training (:func:`moe_apply`) keeps the capacity dispatch: expert weight
+tensors carry the `experts` logical axis (→ mesh `model` axis), and the
+dispatch/combine einsums lower to the all-to-all pattern under pjit.
+Tokens above capacity are dropped (MaxText-style), so every shape stays
+static for SPMD. Its router aux (load-balancing) loss follows
+Switch/Mixtral: ``E · Σ_e f_e · p_e`` with f the dispatch fraction and p
+the mean normalised router score per expert.
 """
 
 from __future__ import annotations
@@ -18,12 +35,14 @@ import jax.numpy as jnp
 from repro.models.layers import ParamSpec, swiglu
 from repro.parallel.ctx import constrain_logical
 
-__all__ = ["moe_specs", "moe_apply", "moe_decode_apply"]
+__all__ = ["moe_specs", "route", "moe_apply", "moe_serve", "scan_split"]
+
+_EXPERT_WEIGHTS = ("we_gate", "we_up", "we_down")
 
 
 def moe_specs(cfg) -> dict:
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
-    return {
+    s = {
         "router": ParamSpec((D, E), ("embed", "experts")),
         "we_gate": ParamSpec((E, D, F), ("experts", "embed", "ff"),
                              fan_in_axes=(1,)),
@@ -32,47 +51,93 @@ def moe_specs(cfg) -> dict:
         "we_down": ParamSpec((E, F, D), ("experts", "ff", "embed"),
                              fan_in_axes=(1,)),
     }
+    if cfg.router == "sigmoid":
+        s["router_bias"] = ParamSpec((E,), ("experts",), init="zeros")
+    if cfg.num_shared_experts:
+        Fs = cfg.num_shared_experts * F
+        s["shared_gate"] = ParamSpec((D, Fs), ("embed", "ff"))
+        s["shared_up"] = ParamSpec((D, Fs), ("embed", "ff"))
+        s["shared_down"] = ParamSpec((Fs, D), ("ff", "embed"))
+    return s
 
 
-def moe_decode_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """Sparse decode path: gather ONLY the top-k experts' weights per row.
+def route(p: dict, x: jax.Array, cfg):
+    """x: (..., D) → (gates (..., k) float32, chosen experts (..., k), and
+    the normalised scores (..., E) float32 the aux loss reads)."""
+    k = cfg.num_experts_per_tok
+    logits = jnp.einsum("...d,de->...e", x, p["router"].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(scores + p["router_bias"].astype(jnp.float32),
+                               k)
+        gates = jnp.take_along_axis(scores, sel, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, sel = jax.lax.top_k(probs, k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * cfg.routed_scaling
+    return gates, sel, probs
 
-    The dense capacity dispatch reads all E experts' weights even for a
-    single token; at decode that makes a top-2-of-8 MoE pay 4× the weight
-    traffic it needs. Gathering (B, k, D, F) slices is cheaper whenever
-    B·k < E — one token decoding (long_500k) reads 2 experts instead of 8.
-    Numerically identical to the dense path (no capacity drops at S=1,
-    C ≥ 1). §Perf hillclimb (mixtral long_500k, iteration 2).
-    """
+
+def _shared(p: dict, x: jax.Array, cfg):
+    if not cfg.num_shared_experts:
+        return 0.0
+    with jax.named_scope("moe.shared"):
+        h = swiglu(jnp.einsum("...d,df->...f", x, p["shared_gate"].astype(x.dtype)),
+                   jnp.einsum("...d,df->...f", x, p["shared_up"].astype(x.dtype)))
+        return jnp.einsum("...f,fd->...d", h, p["shared_down"].astype(x.dtype))
+
+
+def scan_split(group: dict) -> tuple[dict, dict]:
+    """(what a serving layer scan slices per layer, the expert weights it
+    keeps whole) of a stacked layer group: the grouped products take the
+    experts stacked, with the layer's index, since a sliced operand is
+    copied. A group without experts is all sliced."""
+    whole = {k: v for k, v in group.items() if k in _EXPERT_WEIGHTS}
+    return {k: v for k, v in group.items() if k not in whole}, whole
+
+
+def moe_serve(p: dict, x: jax.Array, cfg,
+              layer: jax.Array | None = None) -> jax.Array:
+    """Dropless MoE for prefill and decode. x: (B, S, D) → (B, S, D). With
+    ``layer``, the expert weights in ``p`` are stacked over layers, (L, E,
+    ...), and this layer's index picks its experts."""
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    xt = x[:, 0]                                                   # (B, D)
-    logits = jnp.einsum("bd,de->be", xt, p["router"].astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)    # (B, E)
-    gate_vals, sel = jax.lax.top_k(probs, k)                       # (B, k)
-    gate_vals = (gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-                 ).astype(x.dtype)
-    wg = jnp.take(p["we_gate"], sel, axis=0).astype(x.dtype)       # (B,k,D,F)
-    wu = jnp.take(p["we_up"], sel, axis=0).astype(x.dtype)
-    wd = jnp.take(p["we_down"], sel, axis=0).astype(x.dtype)       # (B,k,F,D)
-    h = swiglu(jnp.einsum("bd,bkdf->bkf", xt, wg),
-               jnp.einsum("bd,bkdf->bkf", xt, wu))
-    y = jnp.einsum("bkf,bkfd,bk->bd", h, wd, gate_vals)
-    return y[:, None, :], jnp.float32(0.0)
+    xt = x.reshape(B * S, D)
+    weights = [p[name] for name in _EXPERT_WEIGHTS]
+    with jax.named_scope("moe.route"):
+        gates, sel, _ = route(p, xt, cfg)                          # (T, k)
+        flat = sel.reshape(-1)
+        order = jnp.argsort(flat, stable=True)     # assignments by expert
+        xs = jnp.take(xt, order // k, axis=0)                      # (T·k, D)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        if layer is not None:
+            L = weights[0].shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), sizes, (layer * E,))
+            weights = [w.reshape(L * E, *w.shape[2:]) for w in weights]
+    with jax.named_scope("moe.experts"):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w.astype(x.dtype), sizes)
+
+        wg, wu, wd = weights
+        h = swiglu(grouped(xs, wg), grouped(xs, wu))
+        ys = grouped(h, wd)                                        # (T·k, D)
+        ys = jnp.take(ys, jnp.argsort(order), axis=0).reshape(B * S, k, D)
+        y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32), gates)
+    y = y.astype(x.dtype).reshape(B, S, D)
+    return y + _shared(p, x, cfg)
 
 
 def moe_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) → (out (B, S, D), aux_loss scalar)."""
+    """Training: x: (B, S, D) → (out (B, S, D), aux_loss scalar)."""
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    if S == 1 and B * k < E:
-        return moe_decode_apply(p, x, cfg)
     capacity = max(int(S * k / E * cfg.capacity_factor), 1)
 
-    logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)   # (B,S,E)
-    gate_vals, sel = jax.lax.top_k(probs, k)                      # (B,S,k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    gate_vals, sel, probs = route(p, x, cfg)                      # (B,S,k)
 
     onehot = jax.nn.one_hot(sel, E, dtype=jnp.float32)            # (B,S,k,E)
     assign = jnp.einsum("bske->bse", onehot)                      # 0/1
@@ -91,7 +156,8 @@ def moe_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     hout = jnp.einsum("ebcf,efd->ebcd", h, p["we_down"].astype(x.dtype))
     hout = constrain_logical(hout, ("experts", "batch", "cap", "act_embed"))
     out = jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), hout)
-    out = constrain_logical(out, ("batch", "seq", "act_embed"))
+    out = constrain_logical(out + _shared(p, x, cfg),
+                            ("batch", "seq", "act_embed"))
 
     # load-balancing aux loss
     frac_dispatch = jnp.mean(assign, axis=(0, 1))                 # (E,)

@@ -2,12 +2,18 @@
 
 One block type per layer "kind":
   attn        causal self-attention (full or sliding window per config) + FFN
+              (the MoE layer in a mixture-of-experts model)
+  attn_dense  causal self-attention + dense MLP: the leading dense layers of
+              a mixture-of-experts model (``first_dense_layers``)
   local_attn  sliding-window attention (hybrid archs) + FFN
   rglru       RG-LRU recurrent mixer + FFN
   ssm         Mamba-2 SSD mixer (no FFN — the mamba block subsumes it)
   enc_attn    bidirectional self-attention (encoder) + FFN
   cross       causal self-attention + cross-attention + FFN (decoder of
               an encoder-decoder)
+
+Self-attention is latent attention (MLA) where ``cfg.kv_lora_rank`` is set;
+its cache is one ``latent`` leaf of rows shared by the heads.
 """
 
 from __future__ import annotations
@@ -35,7 +41,12 @@ def layer_kinds(cfg, *, encoder: bool = False) -> list[str]:
         return [pat[i % len(pat)] for i in range(cfg.num_layers)]
     if cfg.is_encdec:
         return ["cross"] * cfg.num_layers
-    return ["attn"] * cfg.num_layers
+    lead = cfg.first_dense_layers
+    return ["attn_dense"] * lead + ["attn"] * (cfg.num_layers - lead)
+
+
+def _moe(cfg, kind: str) -> bool:
+    return cfg.num_experts > 0 and kind in ("attn", "local_attn")
 
 
 # ------------------------------------------------------------------- specs
@@ -52,7 +63,9 @@ def mlp_specs(cfg) -> dict:
 def block_specs(cfg, kind: str) -> dict:
     D = cfg.d_model
     s: dict = {"pre_norm": ParamSpec((D,), ("embed",), init="ones")}
-    if kind in ("attn", "local_attn", "enc_attn", "cross"):
+    if kind in ("attn", "attn_dense") and cfg.kv_lora_rank:
+        s.update(attn.mla_specs(cfg))
+    elif kind in ("attn", "attn_dense", "local_attn", "enc_attn", "cross"):
         s.update(attn.attn_specs(cfg))
     elif kind == "rglru":
         s.update(rglru_mod.rglru_specs(cfg))
@@ -65,7 +78,7 @@ def block_specs(cfg, kind: str) -> dict:
         s["cross_norm"] = ParamSpec((D,), ("embed",), init="ones")
         s["cross"] = attn.attn_specs(cfg, cross=True)
     s["mlp_norm"] = ParamSpec((D,), ("embed",), init="ones")
-    if cfg.num_experts > 0 and kind in ("attn", "local_attn"):
+    if _moe(cfg, kind):
         s.update(moe_mod.moe_specs(cfg))
     else:
         s.update(mlp_specs(cfg))
@@ -82,9 +95,15 @@ def mlp_apply(p: dict, x: jax.Array, cfg) -> jax.Array:
     return jnp.einsum("bsf,fd->bsd", h, p["wo_mlp"].astype(x.dtype))
 
 
-def _ffn(p: dict, x: jax.Array, cfg, kind: str):
+def _ffn(p: dict, x: jax.Array, cfg, kind: str, *, serving: bool = False,
+         layer: jax.Array | None = None):
+    """The MLP or MoE sublayer with its residual. ``serving`` (prefill and
+    decode) routes an MoE dropless; training keeps the capacity dispatch.
+    With ``layer``, ``p``'s expert weights are stacked over layers."""
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if cfg.num_experts > 0 and kind in ("attn", "local_attn"):
+    if _moe(cfg, kind) and serving:
+        out, aux = moe_mod.moe_serve(p, h, cfg, layer), jnp.float32(0.0)
+    elif _moe(cfg, kind):
         out, aux = moe_mod.moe_apply(p, h, cfg)
     else:
         out, aux = mlp_apply(p, h, cfg), jnp.float32(0.0)
@@ -105,6 +124,8 @@ def block_apply(p: dict, x: jax.Array, cfg, kind: str, *,
         return x + ssm_mod.ssm_apply(p, h, cfg), jnp.float32(0.0)
     if kind == "rglru":
         x = x + rglru_mod.rglru_apply(p, h, cfg)
+    elif cfg.kv_lora_rank and kind in ("attn", "attn_dense"):
+        x = x + attn.mla_apply(p, h, cfg)[0]
     else:
         causal = kind != "enc_attn"
         x = x + attn.attn_apply(p, h, cfg, causal=causal,
@@ -117,9 +138,10 @@ def block_apply(p: dict, x: jax.Array, cfg, kind: str, *,
 
 # ------------------------------------------------------------------ prefill
 def block_prefill(p: dict, x: jax.Array, cfg, kind: str, max_len: int, *,
-                  memory=None):
+                  memory=None, layer: jax.Array | None = None):
     """Like block_apply but also returns this layer's decode cache, padded
-    to ``max_len`` slots (window-bounded for SWA/local)."""
+    to ``max_len`` slots (window-bounded for SWA/local). With ``layer`` this
+    layer's index, ``p``'s expert weights are stacked over layers."""
     h = rms_norm(x, p["pre_norm"], cfg.norm_eps)
     aux = jnp.float32(0.0)
     if kind == "ssm":
@@ -128,35 +150,39 @@ def block_prefill(p: dict, x: jax.Array, cfg, kind: str, max_len: int, *,
     if kind == "rglru":
         out, cache = _rglru_prefill(p, h, cfg)
         x = x + out
-        x, aux = _ffn(p, x, cfg, kind)
+        x, aux = _ffn(p, x, cfg, kind, serving=True, layer=layer)
         return x, cache, aux
+    if cfg.kv_lora_rank:
+        out, rows = attn.mla_apply(p, h, cfg)
+        x, aux = _ffn(p, x + out, cfg, kind, serving=True, layer=layer)
+        return x, {"latent": _to_slots(rows, max_len, axis=1)}, aux
     window = _window_for(cfg, kind)
     out, (k, v) = attn.attn_apply(p, h, cfg, causal=True, window=window,
                                   return_kv=True)
     x = x + out
-    cache = _kv_to_cache(k, v, max_len if window is None else min(window, max_len))
+    slots = max_len if window is None else min(window, max_len)
+    # head-major (B, K, slots, Dh)
+    cache = {"k": _to_slots(jnp.swapaxes(k, 1, 2), slots, axis=2),
+             "v": _to_slots(jnp.swapaxes(v, 1, 2), slots, axis=2)}
     if kind == "cross":
         mkv = attn.cross_memory_kv(p["cross"], memory)
         hc = rms_norm(x, p["cross_norm"], cfg.norm_eps)
         x = x + attn.cross_attn_apply(p["cross"], hc, mkv, cfg)
         cache = {**cache, "enc_k": mkv[0], "enc_v": mkv[1]}
-    x, aux = _ffn(p, x, cfg, kind)
+    x, aux = _ffn(p, x, cfg, kind, serving=True, layer=layer)
     return x, cache, aux
 
 
-def _kv_to_cache(k: jax.Array, v: jax.Array, slots: int) -> dict:
-    """Lay the prefill K/V (B, S, K, Dh) into a head-major ring/flat cache of
-    ``slots`` positions, (B, K, slots, Dh)."""
-    k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
-    S = k.shape[2]
+def _to_slots(x: jax.Array, slots: int, axis: int) -> jax.Array:
+    """Lay prefill rows, positions 0..S-1 along ``axis``, into a ring/flat
+    cache of ``slots`` positions there."""
+    S = x.shape[axis]
     if S >= slots:   # keep the last `slots` positions; ring phase = S % slots
-        shift = (S % slots)
-        k_c = jnp.roll(k[:, :, -slots:], shift, axis=2)
-        v_c = jnp.roll(v[:, :, -slots:], shift, axis=2)
-    else:
-        pad = ((0, 0), (0, 0), (0, slots - S), (0, 0))
-        k_c, v_c = jnp.pad(k, pad), jnp.pad(v, pad)
-    return {"k": k_c, "v": v_c}
+        last = jax.lax.slice_in_dim(x, S - slots, S, axis=axis)
+        return jnp.roll(last, S % slots, axis=axis)
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, slots - S)
+    return jnp.pad(x, pad)
 
 
 def _ssm_prefill(p, h, cfg):
@@ -206,7 +232,7 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
                  kind: str, layer: jax.Array | None = None):
     """One-token step. x: (B, 1, D). ``cache`` holds this layer's leaves or,
     with ``layer`` this layer's index, every layer's leaves stacked on a
-    leading axis. Each leaf is written as little as its kind needs:
+    leading axis (and so do ``p``'s expert weights). Each leaf is written as little as its kind needs:
     attention k/v one row per batch row, a recurrent state whole (it is
     O(B x state)), cross-attention memory not at all. Returns (x, cache)."""
     def read(c):
@@ -221,8 +247,13 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
         out, new = step(p, h, {k: read(c) for k, c in cache.items()}, cfg)
         x = x + out
         if kind == "rglru":
-            x, _ = _ffn(p, x, cfg, kind)
+            x, _ = _ffn(p, x, cfg, kind, serving=True, layer=layer)
         return x, {k: write(cache[k], new[k]) for k in cache}
+    if cfg.kv_lora_rank:
+        out, latent = attn.mla_decode(p, h, cache["latent"], pos, cfg,
+                                      layer=layer)
+        x, _ = _ffn(p, x + out, cfg, kind, serving=True, layer=layer)
+        return x, {"latent": latent}
     window = _window_for(cfg, kind)
     out, ck, cv = attn.attn_decode(p, h, cache["k"], cache["v"], pos, cfg,
                                    window=window, layer=layer)
@@ -232,5 +263,5 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
         hc = rms_norm(x, p["cross_norm"], cfg.norm_eps)
         x = x + attn.cross_attn_apply(
             p["cross"], hc, (read(cache["enc_k"]), read(cache["enc_v"])), cfg)
-    x, _ = _ffn(p, x, cfg, kind)
+    x, _ = _ffn(p, x, cfg, kind, serving=True, layer=layer)
     return x, new_cache

@@ -21,6 +21,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import ParamSpec
+from repro.models.model import stacked
 
 __all__ = ["RULES", "make_rules", "spec_to_pspec", "param_shardings",
            "tree_pspecs", "batch_pspec", "cache_pspecs", "constrain"]
@@ -173,7 +174,7 @@ def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
         shape, _ = sd
         # layer-stacked caches: (L, B, ...) ; unstacked: (B, ...)
         entries = [None] * len(shape)
-        bdim = 1 if len(shape) >= 2 and shape[0] == cfg.num_layers else 0
+        bdim = 1 if stacked(path) else 0
         # attention k/v are head-major (B, K, slots, Dh); the other leaves
         # put a sequence-like dim first (B, F|W-1, ...)
         head_major = path[-1].key in ("k", "v")

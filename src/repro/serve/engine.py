@@ -86,8 +86,6 @@ class ServeEngine:
         self.queue: list[Request] = []
         self.requests: dict[int, Request] = {}
         self._ids = itertools.count()
-        self._stacked = "layers" in M.cache_shapes(cfg, 1, 8) and not isinstance(
-            M.cache_shapes(cfg, 1, 8)["layers"].get("layer_0"), dict)
         self.counters = Counters()
         self.spans = spans
 
@@ -119,15 +117,14 @@ class ServeEngine:
 
     def _splice(self, row_cache, b: int):
         """Insert a batch-1 prefill cache into batched cache row ``b``."""
-        L = self.cfg.num_layers
-
-        def one(full, row):
+        def one(path, full, row):
             # layer-stacked leaves are (L, B, ...); unstacked are (B, ...)
-            if full.ndim >= 2 and full.shape[0] == L and row.shape[0] == L:
+            if M.stacked(path):
                 return full.at[:, b].set(row[:, 0])
             return full.at[b].set(row[0])
 
-        self.cache = jax.tree_util.tree_map(one, self.cache, row_cache)
+        self.cache = jax.tree_util.tree_map_with_path(one, self.cache,
+                                                      row_cache)
 
     def _admit(self):
         for slot_id, slot in enumerate(self.slots):

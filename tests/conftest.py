@@ -12,7 +12,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # `pytest -m "not data_plane"` selects on runners without JAX.
 DATA_PLANE_MODULES = {"test_kernels", "test_kernels_smoke", "test_arch_smoke",
                       "test_train_serve", "test_sharding_rules",
-                      "test_tpu_compile", "test_spans", "test_decode_cache"}
+                      "test_tpu_compile", "test_spans", "test_decode_cache",
+                      "test_mla_moe"}
 
 
 def pytest_collection_modifyitems(items):

@@ -7,7 +7,9 @@ advances by one token a step. After each step the logits of row b must match
 the last logits of a prefill of that row's sequence through the token just
 decoded, and every cache entry outside the rows the step wrote must be
 bit-for-bit what it was. The sliding-window case decodes past its ring
-buffer's wrap-around. Float32 throughout, so the check is structural.
+buffer's wrap-around; the latent-attention case (moonshot) writes one latent
+row a token into each of two stacked groups, its leading dense layer's and
+its MoE layers'. Float32 throughout, so the check is structural.
 """
 
 import jax
@@ -25,11 +27,7 @@ MAX_LEN = 48
 
 
 def _config(arch):
-    cfg = configs.get_smoke(arch).replace(dtype="float32")
-    if cfg.num_experts:
-        # the reference prefill must drop no token: capacity >= S
-        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
-    return cfg
+    return configs.get_smoke(arch).replace(dtype="float32")
 
 
 def _inputs(cfg, row, length, seq):
@@ -40,13 +38,21 @@ def _inputs(cfg, row, length, seq):
     return batch
 
 
-def _splice(full, row_cache, b, num_layers):
+def _splice(full, row_cache, b):
     """Put a batch-1 prefill cache into row ``b`` of the batched cache."""
-    def one(f, r):
-        if f.ndim >= 2 and f.shape[0] == num_layers and r.shape[0] == num_layers:
+    def one(path, f, r):
+        if M.stacked(path):
             return f.at[:, b].set(r[:, 0])
         return f.at[b].set(r[0])
-    return jax.tree_util.tree_map(one, full, row_cache)
+    return jax.tree_util.tree_map_with_path(one, full, row_cache)
+
+
+WRITTEN = ("k", "v", "latent")
+
+
+def _index(key, b, slot):
+    return (Ellipsis, b, slot, slice(None)) if key == "latent" else \
+        (Ellipsis, b, slice(None), slot, slice(None))
 
 
 def _written(path, leaf, pos):
@@ -55,18 +61,19 @@ def _written(path, leaf, pos):
     key = path[-1].key
     if key in ("enc_k", "enc_v"):
         return np.zeros(leaf.shape, bool)            # cross memory: read only
-    if key not in ("k", "v"):
+    if key not in WRITTEN:
         return None
     mask = np.zeros(leaf.shape, bool)
-    slots = leaf.shape[-2]                            # (..., B, K, slots, Dh)
+    slots = leaf.shape[-2]
     for b, p in enumerate(pos):
-        mask[..., b, :, p % slots, :] = True
+        mask[_index(key, b, p % slots)] = True
     return mask
 
 
 @pytest.mark.parametrize("arch", ["granite-8b", "mixtral-8x22b", "mamba2-130m",
                                   "recurrentgemma-2b",
-                                  "seamless-m4t-large-v2"])
+                                  "seamless-m4t-large-v2",
+                                  "moonshot-v1-16b-a3b"])
 def test_decode_writes_only_new_rows(arch):
     cfg = _config(arch)
     B = len(LENGTHS)
@@ -79,7 +86,7 @@ def test_decode_writes_only_new_rows(arch):
     cache = M.init_cache(cfg, B, MAX_LEN)
     for b, n in enumerate(LENGTHS):
         _, row = prefill(params, _inputs(cfg, b, n, seq))
-        cache = _splice(cache, row, b, cfg.num_layers)
+        cache = _splice(cache, row, b)
 
     pos = np.array(LENGTHS, np.int32)
     for _ in range(STEPS):
@@ -93,15 +100,17 @@ def test_decode_writes_only_new_rows(arch):
                                        rtol=2e-3, atol=2e-3)
             # the row written is the one the longer prefill holds there
             for path, got in jax.tree_util.tree_leaves_with_path(new):
-                if path[-1].key not in ("k", "v"):
+                key = path[-1].key
+                if key not in WRITTEN:
                     continue
                 ref = jax.tree_util.tree_leaves_with_path(ref_cache)
                 want = dict((jax.tree_util.keystr(p), x) for p, x in ref)[
                     jax.tree_util.keystr(path)]
                 slot = int(pos[b]) % got.shape[-2]
                 np.testing.assert_allclose(
-                    np.asarray(got[..., b, :, slot, :]),
-                    np.asarray(want[..., 0, :, slot, :]), rtol=1e-4, atol=1e-4)
+                    np.asarray(got[_index(key, b, slot)]),
+                    np.asarray(want[_index(key, 0, slot)]), rtol=1e-4,
+                    atol=1e-4)
         old = jax.tree_util.tree_leaves_with_path(cache)
         for (path, before), after in zip(old, jax.tree_util.tree_leaves(new)):
             mask = _written(path, before, pos)
