@@ -15,6 +15,12 @@ values and runs the usual attention; its decode never expands the cache:
 the key up-projection is absorbed into the query and the value
 up-projection into the output, so the scores and the weighted sum read the
 latent rows directly.
+
+Decode attention reads a cache one of two ways, chosen by the platform of
+the devices the step is compiled for: on TPUs the ragged Pallas kernel
+(``kernels/decode_attention``), which reads only each row's live slots
+(per shard of the cache on a mesh); elsewhere the jnp path, which reads a
+layer's whole cache and masks it.
 """
 
 from __future__ import annotations
@@ -24,8 +30,13 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import (decode_attention,
+                                            grouped_decode_ref,
+                                            latent_decode_ref)
 from repro.kernels.flash_attention import flash_attention
 from repro.models.layers import ParamSpec, apply_rope, rms_norm, rotary_embedding
+from repro.parallel import ctx
+from repro.parallel import sharding as shd
 
 __all__ = ["attn_specs", "attn_apply", "attn_decode", "cross_attn_apply",
            "mla_specs", "mla_apply", "mla_decode"]
@@ -95,6 +106,27 @@ def cross_attn_apply(p: dict, x: jax.Array, memory, cfg):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
 
 
+def _kernel_target():
+    """(mesh, rules) of the step being traced where its devices are TPUs,
+    so decode attention runs the ragged kernel; None for the jnp path."""
+    target = ctx.current()
+    if target is not None and target[0].devices.flat[0].platform == "tpu":
+        return target
+    return None
+
+
+def _ragged(target, q, k, v, pos, layer, cfg, *, scale: float,
+            value_width: int | None = None):
+    """The ragged kernel on ``target``'s mesh, over the cache's own
+    sharding (``v`` None: the latent rows are the values)."""
+    mesh, rules = target
+    stack = k.shape if layer is not None else (1, *k.shape)
+    spec = shd.cache_leaf_pspec(stack, bdim=1, head_major=v is not None,
+                                rules=rules, mesh=mesh, cfg=cfg)
+    return decode_attention(q, k, v, pos, layer, mesh=mesh, cache_spec=spec,
+                            scale=scale, value_width=value_width)
+
+
 def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                 pos: jax.Array, cfg, *, window: int | None = None,
                 layer: jax.Array | None = None):
@@ -130,22 +162,19 @@ def attn_decode(p: dict, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         at = (layer, *at)
     cache_k = cache_k.at[at].set(k_new[:, 0].astype(cache_k.dtype))
     cache_v = cache_v.at[at].set(v_new[:, 0].astype(cache_v.dtype))
-    layer_k = cache_k if layer is None else cache_k[layer]
-    layer_v = cache_v if layer is None else cache_v[layer]
 
-    qf = q.astype(jnp.float32).reshape(B, K, G, Dh)
-    kf = layer_k.astype(jnp.float32)
-    vf = layer_v.astype(jnp.float32)
-    s = jnp.einsum("bkgd,bktd->bkgt", qf, kf) * (Dh ** -0.5)
-    # slot j holds the token `age = (slot - j) mod Smax` steps in the past
-    idx = jnp.arange(Smax)[None, :]
-    age = (slot[:, None] - idx) % Smax                    # (B, Smax); 0 = now
-    valid = age <= jnp.minimum(pos, Smax - 1)[:, None]    # written yet?
-    if window is not None:
-        valid &= age < window
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    pattn = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,bktd->bkgd", pattn, vf).reshape(B, 1, H, Dh)
+    q = q.reshape(B, K, G, Dh)
+    target = _kernel_target()
+    if target is None:
+        o = grouped_decode_ref(q, cache_k, cache_v, pos, layer=layer,
+                               window=window)
+    else:
+        # a ring buffer is never longer than its window: its live slots
+        # are then the first min(pos + 1, Smax)
+        assert window is None or Smax <= window, (Smax, window)
+        o = _ragged(target, q, cache_k, cache_v, pos, layer, cfg,
+                    scale=Dh ** -0.5)
+    o = o.reshape(B, 1, H, Dh)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), p["wo"].astype(x.dtype))
     return out, cache_k, cache_v
 
@@ -241,22 +270,18 @@ def mla_decode(p: dict, x: jax.Array, cache: jax.Array, pos: jax.Array, cfg,
             at = (layer, *at)
         pieces = pieces.at[at].set(row[:, 0].reshape(B, n, w).astype(cache.dtype))
         cache = pieces.reshape(cache.shape)
-        lat = cache if layer is None else cache[layer]
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
                            p["wk_b"].astype(x.dtype))
-        qc = jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1).astype(lat.dtype)
+        qc = jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1).astype(cache.dtype)
     with jax.named_scope("mla.attend"):
         scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-        s = jnp.einsum("bhc,btc->bht", qc, lat,
-                       preferred_element_type=jnp.float32) * scale
-        age = (slot[:, None] - jnp.arange(Smax)[None, :]) % Smax
-        valid = age <= jnp.minimum(pos, Smax - 1)[:, None]
-        s = jnp.where(valid[:, None, :], s, -1e30)
-        pattn = jax.nn.softmax(s, axis=-1).astype(lat.dtype)
-        # the whole row, rope part included, so no C-wide slice of the
-        # cache is copied; the rope part of the sum is dropped after
-        o_lat = jnp.einsum("bht,btc->bhc", pattn, lat,
-                           preferred_element_type=jnp.float32)[..., :C]
+        target = _kernel_target()
+        if target is None:
+            o_lat = latent_decode_ref(qc, cache, pos, scale=scale,
+                                      value_width=C, layer=layer)
+        else:
+            o_lat = _ragged(target, qc[:, None], cache, None, pos, layer,
+                            cfg, scale=scale, value_width=C)[:, 0]
         o = jnp.einsum("bhc,chv->bhv", o_lat.astype(x.dtype),
                        p["wv_b"].astype(x.dtype))
         out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(x.dtype))

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 __all__ = ["ParamSpec", "init_tree", "abstract_tree", "cast_tree",
            "rms_norm", "rotary_embedding", "apply_rope", "swiglu", "geglu",
-           "take_embedding"]
+           "take_embedding", "stacked"]
 
 
 class ParamSpec(NamedTuple):
@@ -114,3 +114,10 @@ def geglu(x_gate: jax.Array, x_up: jax.Array) -> jax.Array:
 
 def take_embedding(table: jax.Array, ids: jax.Array, compute_dtype) -> jax.Array:
     return jnp.take(table, ids, axis=0).astype(compute_dtype)
+
+
+def stacked(path) -> bool:
+    """Is the decode-cache leaf at ``path`` stacked over its group's
+    layers? A stacked leaf sits directly in its group; an unrolled group
+    holds one dict per layer."""
+    return len(path) == 2

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.models.layers import (ParamSpec, abstract_tree, init_tree,
-                                 rms_norm, take_embedding)
+                                 rms_norm, stacked, take_embedding)
 from repro.models.ssm import ssm_cache_shapes
 from repro.parallel.ctx import constrain_logical
 from repro.models.rglru import rglru_cache_shapes
@@ -45,13 +45,6 @@ def _lead(cfg) -> int:
 def _uniform_scan(cfg) -> bool:
     kinds = tfm.layer_kinds(cfg)[_lead(cfg):]
     return cfg.scan_layers and len(set(kinds)) == 1
-
-
-def stacked(path) -> bool:
-    """Is the decode-cache leaf at ``path`` stacked over its group's
-    layers? A stacked leaf sits directly in its group; an unrolled group
-    holds one dict per layer."""
-    return len(path) == 2
 
 
 def _layer(tree, i: int):

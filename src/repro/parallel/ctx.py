@@ -15,7 +15,7 @@ from contextlib import contextmanager
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["sharding_ctx", "constrain_logical"]
+__all__ = ["sharding_ctx", "current", "constrain_logical"]
 
 _TLS = threading.local()
 
@@ -28,6 +28,11 @@ def sharding_ctx(mesh, rules: dict):
         yield
     finally:
         _TLS.ctx = prev
+
+
+def current():
+    """(mesh, rules) of the step being traced, or None outside one."""
+    return getattr(_TLS, "ctx", None)
 
 
 def _pspec(axes, rules) -> P:
@@ -48,7 +53,7 @@ def _pspec(axes, rules) -> P:
 
 
 def constrain_logical(x: jax.Array, axes: tuple) -> jax.Array:
-    ctx = getattr(_TLS, "ctx", None)
+    ctx = current()
     if ctx is None:
         return x
     mesh, rules = ctx

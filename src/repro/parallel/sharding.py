@@ -20,11 +20,11 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.models.layers import ParamSpec
-from repro.models.model import stacked
+from repro.models.layers import ParamSpec, stacked
 
 __all__ = ["RULES", "make_rules", "spec_to_pspec", "param_shardings",
-           "tree_pspecs", "batch_pspec", "cache_pspecs", "constrain"]
+           "tree_pspecs", "batch_pspec", "cache_pspecs", "cache_leaf_pspec",
+           "constrain"]
 
 
 def make_rules(*, multi_pod: bool, fsdp: bool = False,
@@ -164,46 +164,49 @@ def batch_pspec(rules: dict) -> P:
     return P(dp if len(dp) > 1 else (dp[0] if dp else None))
 
 
-def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
-    """PartitionSpecs for a decode cache: batch dim over DP axes, kv-head /
-    state dims over model where divisible."""
+def cache_leaf_pspec(shape, *, bdim: int, head_major: bool, rules: dict,
+                     mesh: Mesh, cfg) -> P:
+    """PartitionSpec for one decode-cache leaf: batch dim ``bdim`` over DP
+    axes, kv-head / state dims over model where divisible. Attention k/v
+    are ``head_major`` (B, K, slots, Dh); the other leaves put a
+    sequence-like dim first (B, F|W-1, ...)."""
     dp = tuple(rules["batch"])
     dp_entry = dp if len(dp) > 1 else (dp[0] if dp else None)
+    entries = [None] * len(shape)
+    dp_n = 1
+    for a in dp:
+        dp_n *= mesh.shape[a]
+    if shape[bdim] % dp_n == 0:
+        entries[bdim] = dp_entry
+    # shard kv-heads/state heads over model when divisible…
+    model_n = mesh.shape["model"]
+    placed = False
+    for i in range(bdim + (1 if head_major else 2), len(shape)):
+        if shape[i] in (cfg.num_kv_heads, cfg.ssm_heads) and \
+                shape[i] % model_n == 0:
+            entries[i] = "model"
+            placed = True
+            break
+    # …else shard the sequence-slots dim (GQA kv < model axis: the
+    # standard sequence-sharded KV cache — keeps a 32k×128-row cache
+    # at ~2.5 GB/chip instead of 40 GB/chip)
+    if not placed and len(shape) >= bdim + 3:
+        slots_dim = bdim + (2 if head_major else 1)
+        if shape[slots_dim] % model_n == 0:
+            entries[slots_dim] = "model"
+    while entries and entries[-1] is None:
+        entries.pop()
+    return P(*entries)
 
-    def one(path, sd):
-        shape, _ = sd
-        # layer-stacked caches: (L, B, ...) ; unstacked: (B, ...)
-        entries = [None] * len(shape)
-        bdim = 1 if stacked(path) else 0
-        # attention k/v are head-major (B, K, slots, Dh); the other leaves
-        # put a sequence-like dim first (B, F|W-1, ...)
-        head_major = path[-1].key in ("k", "v")
-        dp_n = 1
-        for a in dp:
-            dp_n *= mesh.shape[a]
-        if shape[bdim] % dp_n == 0:
-            entries[bdim] = dp_entry
-        # shard kv-heads/state heads over model when divisible…
-        model_n = mesh.shape["model"]
-        placed = False
-        for i in range(bdim + (1 if head_major else 2), len(shape)):
-            if shape[i] in (cfg.num_kv_heads, cfg.ssm_heads) and \
-                    shape[i] % model_n == 0:
-                entries[i] = "model"
-                placed = True
-                break
-        # …else shard the sequence-slots dim (GQA kv < model axis: the
-        # standard sequence-sharded KV cache — keeps a 32k×128-row cache
-        # at ~2.5 GB/chip instead of 40 GB/chip)
-        if not placed and len(shape) >= bdim + 3:
-            slots_dim = bdim + (2 if head_major else 1)
-            if shape[slots_dim] % model_n == 0:
-                entries[slots_dim] = "model"
-        while entries and entries[-1] is None:
-            entries.pop()
-        return P(*entries)
 
+def cache_pspecs(cache_shape_tree, rules: dict, mesh: Mesh, cfg):
+    """PartitionSpecs for a decode cache (:func:`cache_leaf_pspec` per
+    leaf; layer-stacked leaves are (L, B, ...), unstacked (B, ...))."""
     return jax.tree_util.tree_map_with_path(
-        one, cache_shape_tree,
+        lambda path, sd: cache_leaf_pspec(
+            sd[0], bdim=1 if stacked(path) else 0,
+            head_major=path[-1].key in ("k", "v"), rules=rules, mesh=mesh,
+            cfg=cfg),
+        cache_shape_tree,
         is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
         and isinstance(x[0], tuple))

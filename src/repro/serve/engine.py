@@ -62,6 +62,9 @@ class Counters:
     prompt_tokens: int = 0      # tokens those prefills read
     decoded_tokens: int = 0     # tokens the decode steps produced
     slot_steps_active: int = 0  # rows active, summed over decode steps
+    # live cache positions of the active rows, min(pos + 1, max_len) each,
+    # summed over decode steps: what their attention has to read
+    live_positions: int = 0
 
 
 @dataclass
@@ -187,6 +190,8 @@ class ServeEngine:
             c = self.counters
             c.steps += 1
             c.slot_steps_active += len(active)
+            c.live_positions += sum(min(s.pos + 1, self.max_len)
+                                    for s in active)
             c.decoded_tokens += len(active)
             for i, slot in enumerate(self.slots):
                 if not slot.active:

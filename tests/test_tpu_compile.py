@@ -10,6 +10,7 @@ inside a fixture, never at import, so every xdist worker collects the same
 tests and only the worker given this file loads the TPU library.
 """
 
+import math
 import os
 import re
 
@@ -116,21 +117,34 @@ DECODE_CFGS = {
 _INSTR = re.compile(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(")
 
 
-@pytest.mark.parametrize("arch", sorted(DECODE_CFGS))
-def test_decode_updates_cache_in_place(topo, arch):
-    """The decode step writes its rows into the donated cache: no copy of
-    a stacked cache leaf, no whole-layer write of an attention cache, and
-    no temporary as large as one layer of the largest leaf."""
-    fields, B, max_len = DECODE_CFGS[arch]
-    cfg = configs.get(arch).replace(**fields)
-    step = make_serve_step(cfg, make_mesh(topo.devices[:1], (1, 1)),
-                           shd.make_rules(multi_pod=False),
+def _compile_decode(cfg, mesh, B, max_len):
+    step = make_serve_step(cfg, mesh, shd.make_rules(multi_pod=False),
                            global_batch=B, max_len=max_len)
     cache = M.abstract_cache(cfg, B, max_len)
     compiled = step.lower(
         M.abstract_params(cfg, jnp.bfloat16), cache,
         jax.ShapeDtypeStruct((B, 1), jnp.int32),
         jax.ShapeDtypeStruct((B,), jnp.int32)).compile()
+    return cache, compiled
+
+
+def _decode_attention_calls(hlo: str) -> int:
+    """The ragged decode-attention kernel's custom calls in ``hlo``."""
+    return sum("tpu_custom_call" in line and "decode_attention" in line
+               for line in hlo.splitlines())
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE_CFGS))
+def test_decode_updates_cache_in_place(topo, arch):
+    """The decode step writes its rows into the donated cache: no copy of
+    a stacked cache leaf, no whole-layer write of an attention cache, and
+    no temporary as large as one layer of the largest leaf. Its attention
+    reads the cache with the ragged kernel, which takes the stacked leaves
+    as they are."""
+    fields, B, max_len = DECODE_CFGS[arch]
+    cfg = configs.get(arch).replace(**fields)
+    cache, compiled = _compile_decode(
+        cfg, make_mesh(topo.devices[:1], (1, 1)), B, max_len)
 
     leaves = jax.tree_util.tree_leaves_with_path(cache)
     stacked = {leaf.shape for _, leaf in leaves}
@@ -145,6 +159,9 @@ def test_decode_updates_cache_in_place(topo, arch):
                 if op in ("copy", "copy-start") and s in stacked}
     assert not {(op, s) for op, s in ops
                 if op == "dynamic-update-slice" and s in rows}
+    # one kernel call per stacked group of attention layers
+    assert _decode_attention_calls(compiled.as_text()) == len(
+        {path[0].key for path, _ in leaves if path[-1].key in ("k", "latent")})
 
     m = compiled.memory_analysis()
     nbytes = [leaf.size * leaf.dtype.itemsize for _, leaf in leaves]
@@ -152,3 +169,37 @@ def test_decode_updates_cache_in_place(topo, arch):
     # one layer of the largest stacked leaf
     assert m.temp_size_in_bytes < max(
         n // leaf.shape[0] for n, (_, leaf) in zip(nbytes, leaves))
+
+
+# granite's 8 KV heads split over the model axis; Moonlight's latent cache
+# has no head axis, so its slots are split there and the shards' softmaxes
+# merged
+SHARDED_CFGS = {
+    "granite-8b": (dict(num_layers=2), 32, 4096),
+    "moonshot-v1-16b-a3b": (dict(num_layers=3), 64, 8192),
+}
+_COLLECTIVE = re.compile(
+    r"= \(?\w+\[([\d,]*)\][^=]* (all-gather|all-reduce|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
+
+
+@pytest.mark.parametrize("arch", sorted(SHARDED_CFGS))
+def test_sharded_decode_reads_cache_shards_in_place(topo, arch):
+    """On a 2 x 2 mesh the kernel runs per shard of the cache: apart from
+    gathering weights, no collective moves as much as one layer of a
+    cache shard."""
+    fields, B, max_len = SHARDED_CFGS[arch]
+    cfg = configs.get(arch).replace(**fields)
+    mesh = make_mesh(topo.devices, (2, 2))
+    cache, compiled = _compile_decode(cfg, mesh, B, max_len)
+    hlo = compiled.as_text()
+    assert _decode_attention_calls(hlo) >= 1
+    layer_shard = min(leaf.size // leaf.shape[0] // mesh.size
+                      for leaf in jax.tree_util.tree_leaves(cache))
+    weights = {leaf.shape for leaf in
+               jax.tree_util.tree_leaves(M.abstract_params(cfg))}
+    moved = [(op, tuple(int(d) for d in dims.split(",") if d))
+             for dims, op in _COLLECTIVE.findall(hlo)]
+    assert moved
+    assert all(math.prod(s) < layer_shard for _, s in moved
+               if s not in weights), moved

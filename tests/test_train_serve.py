@@ -169,6 +169,27 @@ def test_serve_engine_stamps_counts_and_spans(mesh):
         assert (queue.t0, queue.t1) == (r.t_submit, r.t_admit)
 
 
+def test_serve_engine_counts_live_positions(mesh):
+    """``live_positions`` sums each active row's live cache positions,
+    ``min(pos + 1, max_len)``, over the decode steps: a request with a
+    prompt of ``p`` tokens decodes its later tokens at positions p, p + 1,
+    and so on."""
+    params = M.init_params(CFG, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(4)
+    with mesh:
+        engine = ServeEngine(CFG, mesh, RULES, params, max_batch=2,
+                             max_len=24)
+        for i in range(4):
+            engine.submit(rng.integers(0, CFG.vocab_size, 3 + 5 * i).tolist(),
+                          max_new_tokens=2 + 3 * i)
+        done = engine.run(max_steps=100)
+    want = sum(min(len(r.prompt) + i + 1, engine.max_len)
+               for r in done for i in range(len(r.generated) - 1))
+    assert engine.counters.live_positions == want > 0
+    assert engine.counters.live_positions <= (
+        engine.counters.steps * engine.max_batch * engine.max_len)
+
+
 @pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
 def test_compile_cache_placement(monkeypatch, env):
     """An outside JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
