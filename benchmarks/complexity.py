@@ -69,8 +69,7 @@ def run() -> list[Count]:
             ("src/repro/core", "src/repro/kernels", "src/repro/models",
              "src/repro/parallel", "src/repro/train", "src/repro/serve",
              "src/repro/launch", "src/repro/configs", "src/repro/data",
-             "src/repro/roofline", "src/repro", "tests", "benchmarks",
-             "examples")]
+             "src/repro", "tests", "benchmarks", "examples")]
 
 
 def main() -> None:

@@ -8,8 +8,7 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import (ModelConfig, ShapeConfig, SHAPES, shape_for,
-                                cell_supported)
+from repro.configs.base import ModelConfig
 
 ARCHS = [
     "mamba2-130m",
@@ -44,5 +43,4 @@ def get_smoke(arch: str) -> ModelConfig:
     return _module(arch).SMOKE
 
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "shape_for",
-           "cell_supported", "ARCHS", "get", "get_smoke"]
+__all__ = ["ModelConfig", "ARCHS", "get", "get_smoke"]

@@ -1,7 +1,7 @@
 """Model/run configuration system.
 
 A config is a frozen dataclass; every assigned architecture contributes one
-module in this package exposing ``CONFIG`` (full size, dry-run only) and
+module in this package exposing ``CONFIG`` (the published widths) and
 ``SMOKE`` (reduced same-family config runnable on CPU). ``repro.configs.get``
 resolves ``--arch`` flags.
 """
@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass, field
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "shape_for"]
+__all__ = ["ModelConfig"]
 
 
 @dataclass(frozen=True)
@@ -30,10 +30,6 @@ class ModelConfig:
     attention: str = "full"         # full | swa | none
     window: int = 4096              # sliding window (attention == "swa" / local)
     qkv_bias: bool = False
-    attn_chunked: bool = False      # blockwise online-softmax (XLA flash):
-                                    # O(S·D) peak bytes instead of O(S²)
-    attn_q_block: int = 1024        # chunked-attention tile sizes; carry
-    attn_k_block: int = 1024        # traffic ∝ S/attn_k_block per q tile
 
     # latent attention (MLA, DeepSeek-V2/V3): keys and values expand from
     # one normed latent of kv_lora_rank per token, with a rope part of
@@ -80,9 +76,6 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
-    remat: bool = True
-    scan_layers: bool = True
-    use_pallas: bool = False        # kernels: pallas path (TPU) vs ref path
 
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
@@ -96,12 +89,6 @@ class ModelConfig:
     @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
-
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch decode at 500k context with bounded memory?"""
-        return (self.family in ("ssm", "hybrid")
-                or self.attention == "swa")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -133,33 +120,3 @@ class ModelConfig:
             total += n
         return total
 
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                       # train | prefill | decode
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-def shape_for(name: str) -> ShapeConfig:
-    try:
-        return SHAPES[name]
-    except KeyError:
-        raise KeyError(f"unknown shape {name!r}; have {sorted(SHAPES)}")
-
-
-def cell_supported(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Is (arch × shape) runnable? (DESIGN.md §Arch-applicability skips.)"""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, ("full-attention arch: 500k dense KV decode is not "
-                       "sub-quadratic (skip per assignment)")
-    return True, ""

@@ -8,7 +8,6 @@ CONFIG = ModelConfig(
     d_ff=7680, vocab_size=256000, head_dim=256,
     block_pattern=("rglru", "rglru", "local_attn"), window=2048,
     lru_width=2560, conv_width=4, tie_embeddings=True,
-    scan_layers=False,  # 26 % 3 != 0: pattern remainder → unrolled stack
 )
 
 SMOKE = CONFIG.replace(

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
-from repro.kernels.flash_attention.ref import attention_chunked, attention_ref
+from repro.kernels.flash_attention.ref import attention_ref
 
 __all__ = ["flash_attention"]
 
@@ -24,19 +24,13 @@ def _on_tpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
-                                             "use_pallas", "block_q", "block_k",
-                                             "chunked", "q_chunk", "k_chunk"))
+                                             "use_pallas", "block_q",
+                                             "block_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None, use_pallas: bool = False,
-                    chunked: bool = False,
-                    q_chunk: int = 1024, k_chunk: int = 1024,
                     block_q: int = 128, block_k: int = 128) -> jax.Array:
     """GQA attention. q: (B, Sq, H, D); k, v: (B, Sk, K, D) → (B, Sq, H, D)."""
-    if chunked and not use_pallas:
-        return attention_chunked(q, k, v, causal=causal, window=window,
-                                 scale=scale, q_block=q_chunk,
-                                 k_block=k_chunk)
     if not use_pallas:
         return attention_ref(q, k, v, causal=causal, window=window, scale=scale)
     qt = jnp.swapaxes(q, 1, 2)

@@ -1,2 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers,
-OAR cluster runner."""
+"""Launchers: the mesh constructor, train/serve drivers, OAR cluster runner."""

@@ -4,10 +4,9 @@
         --requests 12 --max-batch 4 --max-new 16
 
 On CPU this serves the reduced smoke config of any assigned architecture;
-on TPU the same entry point takes ``--full`` and the production mesh with
-the `tp2d` serving rules (resident 2-D-sharded weights — see
-EXPERIMENTS.md §Perf Cell B for why serving must not reuse training
-shardings).
+on TPU the same entry point takes ``--full``, and ``--tp2d`` selects the
+serving rules (resident 2-D-sharded weights, so a decode step gathers no
+weights).
 """
 
 from __future__ import annotations
@@ -43,7 +42,7 @@ def main(argv=None) -> None:
 
     cfg = configs.get(args.arch) if args.full else configs.get_smoke(args.arch)
     if jax.default_backend() == "cpu":
-        cfg = cfg.replace(dtype="float32", use_pallas=False)
+        cfg = cfg.replace(dtype="float32")
     mesh = make_mesh(jax.devices(), (-1, 1))
     rules = shd.make_rules(multi_pod=False, tp2d=args.tp2d)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))

@@ -3,11 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch tiny --steps 200 \
         --global-batch 8 --seq-len 128 --ckpt-dir /tmp/tiny_run
 
-On this CPU container it trains the reduced/smoke config of any assigned
-architecture (or the full ``tiny`` ~100M config); on a real TPU slice the
-same entry point takes ``--full --mesh-shape data,model`` and the
-production mesh. Checkpoint/restart: re-running with the same --ckpt-dir
-resumes from the latest step (kill it mid-run to test).
+On a CPU it trains the reduced/smoke config of any assigned architecture
+(or the full ``tiny`` ~100M config); on a TPU slice the same entry point
+takes ``--full`` and ``--model-parallel``. Checkpoint/restart: re-running
+with the same --ckpt-dir resumes from the latest step (kill it mid-run to
+test).
 """
 
 from __future__ import annotations
@@ -47,7 +47,7 @@ def main(argv=None) -> None:
     cfg = configs.get(args.arch) if (args.full or args.arch == "tiny") \
         else configs.get_smoke(args.arch)
     if jax.default_backend() == "cpu":
-        cfg = cfg.replace(dtype="float32", use_pallas=False)
+        cfg = cfg.replace(dtype="float32")
     mesh = make_mesh(jax.devices(), (-1, args.model_parallel))
     rules = shd.make_rules(multi_pod=False, fsdp=args.fsdp)
     print(f"arch={cfg.name} params={cfg.param_count():,} "

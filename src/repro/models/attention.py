@@ -5,8 +5,8 @@ Layout convention: activations (B, S, D); projections keep heads explicit
 axis without reshapes. KV caches are head-major, (B, K, Smax, Dh): the
 layout the decode's score and value dots read, so a layer's cache feeds them
 straight from a layer-stacked buffer, with no transposed copy. Sliding-window
-archs use a ring buffer of size ``window`` so a 500k-token decode holds a
-bounded cache (the systems point that makes `long_500k` runnable at all).
+archs use a ring buffer of size ``window``, so a decode of any length holds
+a bounded cache.
 
 Latent attention (MLA) caches one normed latent row per token, shared by
 every head: (B, Smax, kv_lora_rank + qk_rope_head_dim), the latent followed
@@ -80,9 +80,7 @@ def attn_apply(p: dict, x: jax.Array, cfg, *, causal: bool = True,
     sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
-    o = flash_attention(q, k, v, causal=causal, window=window,
-                        use_pallas=cfg.use_pallas, chunked=cfg.attn_chunked,
-                        q_chunk=cfg.attn_q_block, k_chunk=cfg.attn_k_block)
+    o = flash_attention(q, k, v, causal=causal, window=window)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
     if return_kv:
         return out, (k, v)
@@ -102,7 +100,7 @@ def cross_attn_apply(p: dict, x: jax.Array, memory, cfg):
     mk, mv = memory if isinstance(memory, tuple) else cross_memory_kv(p, memory)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     o = flash_attention(q, mk.astype(x.dtype), mv.astype(x.dtype),
-                        causal=False, use_pallas=cfg.use_pallas)
+                        causal=False)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
 
 
@@ -232,10 +230,7 @@ def mla_apply(p: dict, x: jax.Array, cfg):
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         k = jnp.concatenate([k_nope, k_pe], axis=-1)
     with jax.named_scope("mla.attend"):
-        o = flash_attention(q, k, v, causal=True, use_pallas=cfg.use_pallas,
-                            chunked=cfg.attn_chunked,
-                            q_chunk=cfg.attn_q_block,
-                            k_chunk=cfg.attn_k_block)
+        o = flash_attention(q, k, v, causal=True)
         out = jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(x.dtype))
     return out, row
 

@@ -4,8 +4,8 @@ Models are *metadata first*: every architecture defines its parameter tree
 as a nested dict of :class:`ParamSpec` (shape, logical axes, init). From
 that single source we derive
   - concrete initialisation (smoke tests, the e2e trainer),
-  - abstract ``ShapeDtypeStruct`` trees (the multi-pod dry-run never
-    allocates),
+  - abstract ``ShapeDtypeStruct`` trees (compiling for a described chip
+    allocates nothing),
   - sharding trees (logical axes → mesh axes via `repro.parallel.sharding`).
 
 Forward code is pure-functional JAX over the params dict. No framework
@@ -20,8 +20,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ParamSpec", "init_tree", "abstract_tree", "cast_tree",
-           "rms_norm", "rotary_embedding", "apply_rope", "swiglu", "geglu",
+__all__ = ["ParamSpec", "init_tree", "abstract_tree", "rms_norm",
+           "rotary_embedding", "apply_rope", "swiglu", "geglu",
            "take_embedding", "stacked"]
 
 
@@ -65,14 +65,11 @@ def init_tree(specs, rng, dtype=jnp.float32):
 
 
 def abstract_tree(specs, dtype=jnp.float32):
-    """ShapeDtypeStruct stand-ins (no allocation) for the dry-run."""
+    """ShapeDtypeStruct stand-ins (no allocation) for ahead-of-time
+    compiles."""
     return jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, dtype),
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
-
-
-def cast_tree(tree, dtype):
-    return jax.tree_util.tree_map(lambda a: a.astype(dtype), tree)
 
 
 # --------------------------------------------------------------------------

@@ -1,14 +1,16 @@
 """Model assembly: param shapes, forward/loss, prefill and decode steps for
 every assigned architecture family.
 
-Scan-over-layers is the default (depth-independent HLO ⇒ fast compiles and
-bounded dry-run cost); hybrids with a non-uniform layer pattern unroll.
-A mixture-of-experts model's leading dense layers (``first_dense_layers``)
-form a group of their own, ``dense_layers``, stacked like ``layers`` but
-applied unrolled ahead of the scanned MoE layers. In the params and the
-cache a group is either stacked ({group: {leaf: (L, ...)}}) or holds one
-dict per layer ({group: {"layer_i": {leaf: ...}}}); :func:`stacked` tells
-a cache's apart by that structure.
+A stack whose layer kinds, after the leading dense layers, are all one kind
+is stacked and scanned (depth-independent HLO ⇒ fast compiles); any other
+pattern (recurrentgemma's) unrolls, one dict per layer. Each layer body is
+rematerialised. A mixture-of-experts model's leading dense layers
+(``first_dense_layers``) form a group of their own, ``dense_layers``,
+stacked like ``layers`` but applied unrolled ahead of the scanned MoE
+layers. In the params and the cache a group is either stacked
+({group: {leaf: (L, ...)}}) or holds one dict per layer
+({group: {"layer_i": {leaf: ...}}}); :func:`stacked` tells a cache's apart
+by that structure.
 All public functions treat ``cfg`` as static (hashable frozen dataclass).
 """
 
@@ -37,14 +39,9 @@ def compute_dtype(cfg):
     return jnp.dtype(cfg.dtype)
 
 
-def _lead(cfg) -> int:
-    """Leading layers in the ``dense_layers`` group (scanned stacks only)."""
-    return cfg.first_dense_layers if cfg.scan_layers else 0
-
-
 def _uniform_scan(cfg) -> bool:
-    kinds = tfm.layer_kinds(cfg)[_lead(cfg):]
-    return cfg.scan_layers and len(set(kinds)) == 1
+    """Stacked and scanned: the layers after the leading ones are one kind."""
+    return len(set(tfm.layer_kinds(cfg)[cfg.first_dense_layers:])) == 1
 
 
 def _layer(tree, i: int):
@@ -62,7 +59,7 @@ def param_shapes(cfg) -> dict:
     if not cfg.tie_embeddings:
         specs["unembed"] = ParamSpec((D, V), ("embed", "vocab"))
     if _uniform_scan(cfg):
-        lead = _lead(cfg)
+        lead = cfg.first_dense_layers
         groups = {"dense_layers": (kinds[0], lead),
                   "layers": (kinds[-1], cfg.num_layers - lead)}
         for group, (kind, n) in groups.items():
@@ -97,7 +94,7 @@ def _stack_apply(params, x, cfg, kinds, *, memory=None):
     """Run the layer stack. Returns (x, aux)."""
     layers_p = params["layers"]
     if _uniform_scan(cfg):
-        for i in range(_lead(cfg)):
+        for i in range(cfg.first_dense_layers):
             x, _ = tfm.block_apply(_layer(params["dense_layers"], i), x, cfg,
                                    kinds[i])
         kind = kinds[-1]
@@ -107,18 +104,15 @@ def _stack_apply(params, x, cfg, kinds, *, memory=None):
             h, a = tfm.block_apply(layer_p, h, cfg, kind, memory=memory)
             return (h, aux + a), None
 
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), layers_p)
+        (x, aux), _ = jax.lax.scan(jax.checkpoint(body),
+                                   (x, jnp.float32(0.0)), layers_p)
         return x, aux
     aux = jnp.float32(0.0)
     for i, kind in enumerate(kinds):
-        blk = functools.partial(tfm.block_apply, kind=kind, memory=memory)
-        if cfg.remat:
-            blk = jax.checkpoint(blk, static_argnums=(2,))
-            x, a = blk(layers_p[f"layer_{i}"], x, cfg)
-        else:
-            x, a = blk(layers_p[f"layer_{i}"], x, cfg)
+        blk = jax.checkpoint(
+            functools.partial(tfm.block_apply, kind=kind, memory=memory),
+            static_argnums=(2,))
+        x, a = blk(layers_p[f"layer_{i}"], x, cfg)
         aux = aux + a
     return x, aux
 
@@ -131,9 +125,7 @@ def _encoder_apply(params, cfg, embeds):
         h, _ = tfm.block_apply(layer_p, h, cfg, "enc_attn")
         return (h,), None
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    (x,), _ = jax.lax.scan(body, (embeds,), enc["layers"])
+    (x,), _ = jax.lax.scan(jax.checkpoint(body), (embeds,), enc["layers"])
     return rms_norm(x, enc["final_norm"], cfg.norm_eps)
 
 
@@ -212,7 +204,7 @@ def cache_shapes(cfg, batch: int, max_len: int, dtype=None) -> dict:
     dtype = compute_dtype(cfg) if dtype is None else dtype
     kinds = tfm.layer_kinds(cfg)
     if _uniform_scan(cfg):
-        lead = _lead(cfg)
+        lead = cfg.first_dense_layers
         out = {}
         for group, kind, n in (("dense_layers", kinds[0], lead),
                                ("layers", kinds[-1], cfg.num_layers - lead)):
@@ -253,7 +245,7 @@ def decode_step(params, cfg, cache, tokens, pos):
     out = {}
     if _uniform_scan(cfg):
         # leading layers unrolled, each writing its rows into its stack
-        for i in range(_lead(cfg)):
+        for i in range(cfg.first_dense_layers):
             x, out["dense_layers"] = tfm.block_decode(
                 _layer(params["dense_layers"], i), x,
                 out.get("dense_layers", cache.get("dense_layers")), pos, cfg,
@@ -273,7 +265,7 @@ def decode_step(params, cfg, cache, tokens, pos):
 
         (x, out["layers"]), _ = jax.lax.scan(
             body, (x, layers_c),
-            (sliced, jnp.arange(cfg.num_layers - _lead(cfg))))
+            (sliced, jnp.arange(cfg.num_layers - cfg.first_dense_layers)))
     else:
         out["layers"] = {}
         for i, kind in enumerate(kinds):
@@ -302,7 +294,7 @@ def prefill(params, cfg, batch, max_len: int):
     out = {}
     if _uniform_scan(cfg):
         lead = []
-        for i in range(_lead(cfg)):
+        for i in range(cfg.first_dense_layers):
             x, c, _ = tfm.block_prefill(_layer(params["dense_layers"], i), x,
                                         cfg, kinds[i], max_len, memory=memory)
             lead.append(c)
@@ -319,8 +311,8 @@ def prefill(params, cfg, batch, max_len: int):
                 layer=i)
             return h, layer_cache
 
-        x, out["layers"] = jax.lax.scan(
-            body, x, (sliced, jnp.arange(cfg.num_layers - _lead(cfg))))
+        n = cfg.num_layers - cfg.first_dense_layers
+        x, out["layers"] = jax.lax.scan(body, x, (sliced, jnp.arange(n)))
     else:
         out["layers"] = {}
         for i, kind in enumerate(kinds):

@@ -60,7 +60,7 @@ def rglru_apply(p: dict, x: jax.Array, cfg) -> jax.Array:
     u = jnp.einsum("bsd,dw->bsw", x, p["in_x"].astype(x.dtype))
     u = _causal_conv(u, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype))
     a, b = _gates(p, u)
-    h = lru_scan(a, b, use_pallas=cfg.use_pallas)
+    h = lru_scan(a, b)
     g = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", x, p["in_gate"].astype(x.dtype)))
     return jnp.einsum("bsw,wd->bsd", h * g, p["out_w"].astype(x.dtype))
 

@@ -75,7 +75,7 @@ def ssm_apply(p: dict, x: jax.Array, cfg) -> jax.Array:
                          + p["dt_bias"].astype(jnp.float32))     # (B,S,H)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))                 # (H,)
     xh = xs.reshape(B, S, H, P)
-    y = ssd(xh, dt, A, Bm, Cm, chunk=cfg.ssm_chunk, use_pallas=cfg.use_pallas)
+    y = ssd(xh, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
     y = y + p["D_skip"].astype(x.dtype)[None, None, :, None] * xh
     y = y.reshape(B, S, d_inner)
     y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)

@@ -221,7 +221,7 @@ def _rglru_prefill(p, h, cfg):
     uc = rglru_mod._causal_conv(u, p["conv_w"].astype(h.dtype),
                                 p["conv_b"].astype(h.dtype))
     a, b = rglru_mod._gates(p, uc)
-    hseq = rglru_mod.lru_scan(a, b, use_pallas=cfg.use_pallas)
+    hseq = rglru_mod.lru_scan(a, b)
     g = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", h, p["in_gate"].astype(h.dtype)))
     out = jnp.einsum("bsw,wd->bsd", hseq * g, p["out_w"].astype(h.dtype))
     return out, {"conv": tail, "h": hseq[:, -1].astype(jnp.float32)}

@@ -5,6 +5,10 @@ without threading mesh objects through every call.
 calls :func:`constrain_logical(x, ("batch", "seq", "vocab"))` at activation
 boundaries (embeddings, logits, MoE dispatch). Outside any context the call
 is the identity, so single-device smoke tests pay nothing.
+
+The logical-axis arithmetic lives here too, once: :func:`axes_to_pspec`
+maps logical axes to a PartitionSpec, and :func:`fit_pspec` drops the mesh
+axes a dim does not divide by. ``sharding.py`` and the step makers use both.
 """
 
 from __future__ import annotations
@@ -15,7 +19,8 @@ from contextlib import contextmanager
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["sharding_ctx", "current", "constrain_logical"]
+__all__ = ["sharding_ctx", "current", "axes_to_pspec", "fit_pspec",
+           "constrain_logical"]
 
 _TLS = threading.local()
 
@@ -35,7 +40,9 @@ def current():
     return getattr(_TLS, "ctx", None)
 
 
-def _pspec(axes, rules) -> P:
+def axes_to_pspec(axes, rules: dict) -> P:
+    """PartitionSpec for logical ``axes`` under ``rules``; a mesh axis may
+    appear in at most one dim."""
     entries = []
     used: set[str] = set()
     for ax in axes:
@@ -47,6 +54,29 @@ def _pspec(axes, rules) -> P:
             entries.append(mesh_axes[0])
         else:
             entries.append(mesh_axes)
+    return _trim(entries)
+
+
+def fit_pspec(pspec: P, shape: tuple, mesh) -> P:
+    """``pspec`` without the mesh axes a dim of ``shape`` does not divide
+    by (10 heads on a 16-way model axis, a batch of 1): those dims are
+    replicated rather than refused."""
+    entries = list(tuple(pspec)) + [None] * (len(shape) - len(tuple(pspec)))
+    for i, entry in enumerate(entries):
+        if entry is None:
+            continue
+        axes = entry if isinstance(entry, tuple) else (entry,)
+        keep, n = [], 1
+        for a in axes:
+            if shape[i] % (n * mesh.shape[a]) == 0:
+                keep.append(a)
+                n *= mesh.shape[a]
+        entries[i] = tuple(keep) if len(keep) > 1 else (keep[0] if keep else None)
+    return _trim(entries)
+
+
+def _trim(entries: list) -> P:
+    """Canonical form: no trailing ``None``."""
     while entries and entries[-1] is None:
         entries.pop()
     return P(*entries)
@@ -57,19 +87,5 @@ def constrain_logical(x: jax.Array, axes: tuple) -> jax.Array:
     if ctx is None:
         return x
     mesh, rules = ctx
-    pspec = _pspec(axes, rules)
-    # drop axes the dim is not divisible by (mirrors sharding.spec_to_pspec)
-    entries = list(tuple(pspec)) + [None] * (x.ndim - len(tuple(pspec)))
-    for i, entry in enumerate(entries):
-        if entry is None:
-            continue
-        axs = entry if isinstance(entry, tuple) else (entry,)
-        keep, n = [], 1
-        for a in axs:
-            if x.shape[i] % (n * mesh.shape[a]) == 0:
-                keep.append(a)
-                n *= mesh.shape[a]
-        entries[i] = tuple(keep) if len(keep) > 1 else (keep[0] if keep else None)
-    while entries and entries[-1] is None:
-        entries.pop()
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*entries)))
+    pspec = fit_pspec(axes_to_pspec(axes, rules), x.shape, mesh)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))

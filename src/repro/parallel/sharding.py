@@ -1,18 +1,16 @@
 """Logical-axis sharding rules: ParamSpec.axes → PartitionSpec.
 
 Every parameter/cache/activation dimension carries a *logical* axis name;
-a rule set maps logical names to mesh axes. Two built-in rule sets:
+a rule set maps logical names to mesh axes. :func:`make_rules` builds one:
 
 ``baseline``   plain DP × TP: batch over (pod, data); vocab/heads/ff/experts
                over model; parameters replicated across the data axis (the
                classic megatron-style layout).
-``fsdp``       beyond-baseline: additionally shards every parameter's
-               `embed` dim over (pod, data) — fully-sharded data parallel —
-               so params+optimizer state scale with the whole mesh. This is
-               the optimized configuration measured in EXPERIMENTS.md §Perf.
-
-Rules are plain dicts so experiments can derive variants (the hillclimb
-edits one entry at a time and re-lowers).
+``fsdp``       additionally shards every parameter's `embed` dim over
+               (pod, data) — fully-sharded data parallel — so params and
+               optimizer state scale with the whole mesh.
+``tp2d``       serving: parameters sharded 2-D over (data × model) on the
+               ff dim and resident, with no batch sharding.
 """
 
 from __future__ import annotations
@@ -21,35 +19,20 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import ParamSpec, stacked
+from repro.parallel.ctx import axes_to_pspec, fit_pspec
 
-__all__ = ["RULES", "make_rules", "spec_to_pspec", "param_shardings",
-           "tree_pspecs", "batch_pspec", "cache_pspecs", "cache_leaf_pspec",
-           "constrain"]
+__all__ = ["make_rules", "spec_to_pspec", "param_shardings", "batch_pspec",
+           "cache_pspecs", "cache_leaf_pspec"]
 
 
 def make_rules(*, multi_pod: bool, fsdp: bool = False,
-               seq_shard: bool = False, zero: bool = False,
                tp2d: bool = False) -> dict:
     dp = ("pod", "data") if multi_pod else ("data",)
-    if zero:
-        # Pure ZeRO-3 data parallel over the WHOLE mesh: batch and every
-        # parameter's embed dim shard over (pod, data, model); no tensor
-        # parallelism. Beats DP×TP when a head/ff/expert count does not
-        # divide the model axis (e.g. qwen's 40 heads on a 16-way axis
-        # would replicate all attention compute 16×). §Perf hillclimb.
-        dpz = dp + ("model",)
-        return {
-            "batch": dpz, "embed": dpz,
-            "vocab": (), "heads": (), "kv_heads": (), "ff": (),
-            "experts": (), "head": (), "layers": (), "seq": (),
-            "act_embed": (), "cap": (), None: (),
-        }
     if tp2d:
         # Serving rules: parameters sharded 2-D over (data × model) on the
         # ff dim, everything resident — NO per-step FSDP all-gather (which
         # at decode batch=1 costs ~GBs of wire per layer for zero reuse).
         # The per-layer collective is one small activation all-reduce.
-        # §Perf hillclimb (mixtral long_500k).
         return {
             "batch": (), "embed": (),
             "vocab": ("model",), "heads": ("model",), "kv_heads": ("model",),
@@ -57,7 +40,7 @@ def make_rules(*, multi_pod: bool, fsdp: bool = False,
             "head": (), "layers": (), "seq": (),
             "act_embed": (), "cap": (), None: (),
         }
-    rules = {
+    return {
         "batch": dp,
         "vocab": ("model",),
         "heads": ("model",),
@@ -67,90 +50,18 @@ def make_rules(*, multi_pod: bool, fsdp: bool = False,
         "embed": dp if fsdp else (),
         "head": (),
         "layers": (),
-        "seq": dp if seq_shard else (),   # sequence parallelism (long prefill)
+        "seq": (),
         "act_embed": (),                  # activation d_model dim
         "cap": (),                        # MoE capacity dim
         None: (),
     }
-    return rules
 
 
-RULES = {
-    "baseline": make_rules(multi_pod=False),
-    "baseline_mp": make_rules(multi_pod=True),
-    "fsdp": make_rules(multi_pod=False, fsdp=True),
-    "fsdp_mp": make_rules(multi_pod=True, fsdp=True),
-    "zero": make_rules(multi_pod=False, zero=True),
-    "zero_mp": make_rules(multi_pod=True, zero=True),
-    "tp2d": make_rules(multi_pod=False, tp2d=True),
-    "tp2d_mp": make_rules(multi_pod=True, tp2d=True),
-}
-
-
-def _axes_to_pspec(axes, rules: dict, shape=None) -> P:
-    out = []
-    used: set[str] = set()   # a mesh axis may appear in at most one dim
-    for i, ax in enumerate(axes):
-        mesh_axes = rules.get(ax, ())
-        if mesh_axes is None:
-            mesh_axes = ()
-        mesh_axes = tuple(a for a in mesh_axes if a not in used)
-        used.update(mesh_axes)
-        if not mesh_axes:
-            out.append(None)
-        elif len(mesh_axes) == 1:
-            out.append(mesh_axes[0])
-        else:
-            out.append(mesh_axes)
-    # trim trailing Nones (canonical form)
-    while out and out[-1] is None:
-        out.pop()
-    return P(*out)
-
-
-def _divisible(shape, pspec: P, mesh: Mesh) -> bool:
-    for dim, entry in zip(shape, tuple(pspec)):
-        if entry is None:
-            continue
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        n = 1
-        for a in axes:
-            n *= mesh.shape[a]
-        if dim % n != 0:
-            return False
-    return True
-
-
-def spec_to_pspec(spec: ParamSpec, rules: dict, mesh: Mesh | None = None) -> P:
-    """PartitionSpec for one ParamSpec; falls back to dropping mesh axes a
-    dim is not divisible by (e.g. 10 heads on a 16-way model axis →
+def spec_to_pspec(spec: ParamSpec, rules: dict, mesh: Mesh) -> P:
+    """PartitionSpec for one ParamSpec on ``mesh``, without the mesh axes a
+    dim does not divide by (e.g. 10 heads on a 16-way model axis →
     replicate rather than fail)."""
-    pspec = _axes_to_pspec(spec.axes, rules)
-    if mesh is None or _divisible(spec.shape, pspec, mesh):
-        return pspec
-    # drop offending axes one dim at a time
-    entries = list(tuple(pspec)) + [None] * (len(spec.shape) - len(tuple(pspec)))
-    for i, entry in enumerate(entries):
-        if entry is None:
-            continue
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        keep = []
-        n = 1
-        for a in axes:
-            if spec.shape[i] % (n * mesh.shape[a]) == 0:
-                keep.append(a)
-                n *= mesh.shape[a]
-        entries[i] = tuple(keep) if len(keep) > 1 else (keep[0] if keep else None)
-    while entries and entries[-1] is None:
-        entries.pop()
-    return P(*entries)
-
-
-def tree_pspecs(specs, rules: dict, mesh: Mesh | None = None):
-    """Map a nested ParamSpec tree to a PartitionSpec tree."""
-    return jax.tree_util.tree_map(
-        lambda s: spec_to_pspec(s, rules, mesh), specs,
-        is_leaf=lambda x: isinstance(x, ParamSpec))
+    return fit_pspec(axes_to_pspec(spec.axes, rules), spec.shape, mesh)
 
 
 def param_shardings(specs, rules: dict, mesh: Mesh):

@@ -1,8 +1,9 @@
 """Distributed step functions: train_step / prefill_step / serve_step.
 
 Each maker binds (config, mesh, rules) and returns a jitted function with
-explicit in/out shardings (pjit). The dry-run lowers these against
-ShapeDtypeStruct inputs; smoke tests and the tiny trainer execute them.
+explicit in/out shardings (pjit). The trainer, the serving engine and the
+smoke tests execute them; ``tests/test_tpu_compile.py`` lowers them against
+ShapeDtypeStruct inputs for a described TPU.
 
 Distributed-optimization features:
   * microbatch gradient accumulation (``lax.scan`` over the leading
@@ -11,13 +12,12 @@ Distributed-optimization features:
     carries the stacked cache and writes only each row's new key and value
     (``models.model.decode_step``), so no step copies the cache,
   * activation sharding constraints via repro.parallel.ctx,
-  * rematerialised layer stacks (cfg.remat) — compute/comm overlap then
-    falls out of XLA's latency-hiding scheduler on real hardware.
+  * rematerialised layer stacks (``models.model`` checkpoints each layer
+    body) — compute/comm overlap then falls out of XLA's latency-hiding
+    scheduler on real hardware.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import model as M
 from repro.parallel import sharding as shd
-from repro.parallel.ctx import sharding_ctx
+from repro.parallel.ctx import fit_pspec, sharding_ctx
 from repro.train.optimizer import OptConfig, adamw_update, init_opt
 
 __all__ = ["make_train_step", "make_serve_step", "make_prefill_step",
@@ -34,17 +34,17 @@ __all__ = ["make_train_step", "make_serve_step", "make_prefill_step",
 
 
 # ----------------------------------------------------------------- state
-def abstract_train_state(cfg, dtype=jnp.float32, moments_dtype=jnp.float32):
+def abstract_train_state(cfg, dtype=jnp.float32):
     params = M.abstract_params(cfg, dtype)
     like = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, moments_dtype), t)
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32), t)
     return {"params": params, "opt": {"mu": like(params), "nu": like(params)},
             "step": jax.ShapeDtypeStruct((), jnp.int32)}
 
 
-def init_train_state(cfg, rng, dtype=jnp.float32, opt: OptConfig | None = None):
+def init_train_state(cfg, rng, dtype=jnp.float32):
     params = M.init_params(cfg, rng, dtype)
-    return {"params": params, "opt": init_opt(params, opt),
+    return {"params": params, "opt": init_opt(params),
             "step": jnp.int32(0)}
 
 
@@ -55,28 +55,10 @@ def train_state_shardings(cfg, mesh, rules):
             "step": NamedSharding(mesh, P())}
 
 
-def _fit_pspec(pspec: P, shape: tuple, mesh) -> P:
-    """Drop mesh axes a dim is not divisible by (batch=1 cells etc.)."""
-    entries = list(tuple(pspec)) + [None] * (len(shape) - len(tuple(pspec)))
-    for i, entry in enumerate(entries):
-        if entry is None:
-            continue
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        keep, n = [], 1
-        for a in axes:
-            if shape[i] % (n * mesh.shape[a]) == 0:
-                keep.append(a)
-                n *= mesh.shape[a]
-        entries[i] = tuple(keep) if len(keep) > 1 else (keep[0] if keep else None)
-    while entries and entries[-1] is None:
-        entries.pop()
-    return P(*entries)
-
-
 # ----------------------------------------------------------------- batches
-def batch_struct(cfg, global_batch: int, seq_len: int, *, dtype=None):
+def abstract_batch(cfg, global_batch: int, seq_len: int):
     """ShapeDtypeStructs for one training/prefill batch."""
-    dt = M.compute_dtype(cfg) if dtype is None else dtype
+    dt = M.compute_dtype(cfg)
     F = cfg.frontend_tokens
     text = seq_len - F if cfg.family == "vlm" else seq_len
     b = {"tokens": jax.ShapeDtypeStruct((global_batch, text), jnp.int32)}
@@ -84,17 +66,6 @@ def batch_struct(cfg, global_batch: int, seq_len: int, *, dtype=None):
         b["vision_embeds"] = jax.ShapeDtypeStruct((global_batch, F, cfg.d_model), dt)
     if cfg.family == "audio":
         b["audio_embeds"] = jax.ShapeDtypeStruct((global_batch, F, cfg.d_model), dt)
-    return b
-
-
-def abstract_batch(cfg, global_batch: int, seq_len: int, *, microbatches: int = 1,
-                   dtype=None):
-    b = batch_struct(cfg, global_batch, seq_len, dtype=dtype)
-    if microbatches > 1:
-        assert global_batch % microbatches == 0
-        b = {k: jax.ShapeDtypeStruct((microbatches, v.shape[0] // microbatches,
-                                      *v.shape[1:]), v.dtype)
-             for k, v in b.items()}
     return b
 
 
@@ -117,28 +88,13 @@ def batch_shardings(cfg, mesh, rules, *, microbatches: int = 1):
 
 # -------------------------------------------------------------- train step
 def make_train_step(cfg, mesh, rules, *, opt: OptConfig | None = None,
-                    microbatches: int = 1, unroll_mb: bool = False,
-                    bf16_params: bool = False):
-    """``unroll_mb`` replaces the gradient-accumulation lax.scan with a
-    python loop — used ONLY by the dry-run's cost extrapolation, because
-    XLA's cost_analysis counts a scan body once (the scan is what runs).
-
-    ``bf16_params``: mixed precision — cast the f32 master params to the
-    compute dtype ONCE at step start (on their shards, before any FSDP
-    all-gather), so every per-layer gather and weight read moves bf16
-    instead of f32. Grads flow back f32 through the cast; AdamW updates
-    the f32 masters. §Perf hillclimb."""
+                    microbatches: int = 1):
     opt = opt or OptConfig()
     state_sh = train_state_shardings(cfg, mesh, rules)
     batch_sh = batch_shardings(cfg, mesh, rules, microbatches=microbatches)
     metric_sh = NamedSharding(mesh, P())
-    cdt = M.compute_dtype(cfg)
 
     def loss_of(params, batch):
-        if bf16_params:
-            params = jax.tree_util.tree_map(
-                lambda p: p.astype(cdt) if p.dtype == jnp.float32 else p,
-                params)
         with sharding_ctx(mesh, rules):
             return M.loss_fn(params, cfg, batch)
 
@@ -146,18 +102,6 @@ def make_train_step(cfg, mesh, rules, *, opt: OptConfig | None = None,
         params = state["params"]
         if microbatches == 1:
             loss, grads = jax.value_and_grad(loss_of)(params, batch)
-        elif unroll_mb:
-            loss = jnp.float32(0.0)
-            grads = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            for i in range(microbatches):
-                mb = {k: v[i] for k, v in batch.items()}
-                l, g = jax.value_and_grad(loss_of)(params, mb)
-                loss = loss + l
-                grads = jax.tree_util.tree_map(jnp.add, grads, g)
-            inv = 1.0 / microbatches
-            loss = loss * inv
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         else:
             def acc_body(carry, mb):
                 loss_acc, grads_acc = carry
@@ -188,8 +132,7 @@ def make_train_step(cfg, mesh, rules, *, opt: OptConfig | None = None,
 
 
 # -------------------------------------------------------------- serve steps
-def make_serve_step(cfg, mesh, rules, *, global_batch: int, max_len: int,
-                    param_dtype=None):
+def make_serve_step(cfg, mesh, rules, *, global_batch: int, max_len: int):
     """One-token decode step over a persistent sharded cache (donated)."""
     cache_sh = jax.tree_util.tree_map(
         lambda p: NamedSharding(mesh, p),
@@ -200,9 +143,9 @@ def make_serve_step(cfg, mesh, rules, *, global_batch: int, max_len: int,
     bp = shd.batch_pspec(rules)
     dp = tuple(bp)[0]
     B, V = global_batch, cfg.vocab_size
-    tok_sh = NamedSharding(mesh, _fit_pspec(P(dp, None), (B, 1), mesh))
-    pos_sh = NamedSharding(mesh, _fit_pspec(P(dp), (B,), mesh))
-    logit_sh = NamedSharding(mesh, _fit_pspec(P(dp, "model"), (B, V), mesh))
+    tok_sh = NamedSharding(mesh, fit_pspec(P(dp, None), (B, 1), mesh))
+    pos_sh = NamedSharding(mesh, fit_pspec(P(dp), (B,), mesh))
+    logit_sh = NamedSharding(mesh, fit_pspec(P(dp, "model"), (B, V), mesh))
 
     def serve_step(params, cache, tokens, pos):
         with sharding_ctx(mesh, rules):
@@ -226,7 +169,7 @@ def make_prefill_step(cfg, mesh, rules, *, global_batch: int, seq_len: int,
     batch_sh = batch_shardings(cfg, mesh, rules)
     bp = shd.batch_pspec(rules)
     logit_sh = NamedSharding(
-        mesh, _fit_pspec(P(tuple(bp)[0], "model"),
+        mesh, fit_pspec(P(tuple(bp)[0], "model"),
                          (global_batch, cfg.vocab_size), mesh))
 
     def prefill_step(params, batch):

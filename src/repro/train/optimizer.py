@@ -1,8 +1,8 @@
 """AdamW with global-norm clipping, as pure pytree functions.
 
 Optimizer state mirrors the param tree (mu, nu), so the same sharding tree
-applies — under FSDP rules the optimizer state is fully sharded too, which
-is what makes the 405B train cell fit. No external dependency (optax is not
+applies — under FSDP rules the optimizer state is fully sharded too. The
+moments are float32. No external dependency (optax is not
 in the image); the update is the textbook decoupled-weight-decay Adam.
 """
 
@@ -25,15 +25,10 @@ class OptConfig:
     weight_decay: float = 0.1
     clip_norm: float = 1.0
     warmup_steps: int = 100
-    # Adam moments in bf16 (f32 math, bf16 storage): halves+quarters the
-    # optimizer-state footprint — 405B state drops 12→8 B/param, which is
-    # what makes the llama3-405b train cell placeable (§Perf).
-    moments_dtype: str = "float32"
 
 
-def init_opt(params, oc: "OptConfig | None" = None):
-    dt = jnp.dtype((oc or OptConfig()).moments_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)  # noqa: E731
+def init_opt(params):
+    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)  # noqa: E731
     return {"mu": jax.tree_util.tree_map(zeros, params),
             "nu": jax.tree_util.tree_map(zeros, params)}
 
@@ -58,16 +53,14 @@ def adamw_update(grads, opt_state, params, oc: OptConfig, step: jax.Array):
     c1 = 1.0 - oc.b1 ** t
     c2 = 1.0 - oc.b2 ** t
 
-    mdt = jnp.dtype(oc.moments_dtype)
-
     def upd(p, g, mu, nu):
         g = g.astype(jnp.float32) * scale
-        mu = oc.b1 * mu.astype(jnp.float32) + (1 - oc.b1) * g
-        nu = oc.b2 * nu.astype(jnp.float32) + (1 - oc.b2) * jnp.square(g)
+        mu = oc.b1 * mu + (1 - oc.b1) * g
+        nu = oc.b2 * nu + (1 - oc.b2) * jnp.square(g)
         step_dir = (mu / c1) / (jnp.sqrt(nu / c2) + oc.eps)
         newp = p.astype(jnp.float32) - lr * (step_dir + oc.weight_decay
                                              * p.astype(jnp.float32))
-        return newp.astype(p.dtype), mu.astype(mdt), nu.astype(mdt)
+        return newp.astype(p.dtype), mu, nu
 
     flat_p, treedef = jax.tree_util.tree_flatten(params)
     flat_g = treedef.flatten_up_to(grads)

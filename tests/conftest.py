@@ -3,8 +3,6 @@ import sys
 
 import pytest
 
-# tests must see the real device count (1), NOT the dry-run's 512 — the
-# dry-run sets its flag itself, in its own process.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Suite split (markers registered in pytest.ini): the data-plane modules

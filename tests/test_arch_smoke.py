@@ -1,6 +1,7 @@
 """Per-architecture smoke tests: instantiate the REDUCED same-family config,
 run one forward/train step and a prefill→decode step on CPU; assert output
-shapes and no NaNs. The FULL configs are exercised only via the dry-run."""
+shapes and no NaNs. The FULL configs are compiled, not run, by
+test_tpu_compile.py."""
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,49 @@ def test_smoke_prefill_then_decode(arch, mesh):
     logits2, cache2 = M.decode_step(params, cfg, cache, tok, pos)
     assert logits2.shape == (B, cfg.vocab_size)
     assert not np.any(np.isnan(np.asarray(logits2)))
+
+
+@pytest.mark.parametrize("arch", configs.ARCHS + ["tiny"])
+def test_stack_layout_follows_layer_kinds(arch):
+    """The params and the cache hold a stacked ``layers`` group (and a
+    stacked ``dense_layers`` group of the leading dense layers) exactly
+    when the layer kinds after those are all one kind; otherwise one
+    ``layer_i`` dict per layer. Only the hybrid pattern unrolls."""
+    from repro.models import transformer as tfm
+    from repro.models.layers import ParamSpec
+
+    cfg = configs.get_smoke(arch)
+    lead = cfg.first_dense_layers
+    uniform = len(set(tfm.layer_kinds(cfg)[lead:])) == 1
+    assert uniform == (cfg.family != "hybrid")
+    params = M.param_shapes(cfg)
+    cache = M.cache_shapes(cfg, B, S)
+    groups = {"layers": cfg.num_layers - lead, "dense_layers": lead}
+
+    def leading(tree, is_leaf, dim):
+        return {dim(x) for x in jax.tree_util.tree_leaves(tree, is_leaf)}
+
+    def spec_dims(tree):
+        return leading(tree, lambda x: isinstance(x, ParamSpec),
+                       lambda sp: (sp.axes[0], sp.shape[0]))
+
+    def cache_dims(tree):           # cache leaves are (shape, dtype)
+        return leading(tree, lambda x: isinstance(x, tuple)
+                       and isinstance(x[0], tuple), lambda sd: sd[0][0])
+
+    for tree in (params, cache):
+        if uniform:
+            assert {g for g in groups if g in tree} == \
+                {g for g, n in groups.items() if n}
+        else:
+            assert "dense_layers" not in tree
+            assert set(tree["layers"]) == {
+                f"layer_{i}" for i in range(cfg.num_layers)}
+    if uniform:
+        for g, n in groups.items():
+            if n:
+                assert spec_dims(params[g]) == {("layers", n)}
+                assert cache_dims(cache[g]) == {n}
 
 
 @pytest.mark.parametrize("arch", [

@@ -171,42 +171,3 @@ def test_ssd_prefill_decode_agree():
                                Cm[:, -1])
     np.testing.assert_allclose(np.asarray(y), np.asarray(full[:, -1]),
                                rtol=2e-4, atol=2e-4)
-
-
-# ------------------------------------------------- chunked (XLA flash)
-@pytest.mark.parametrize("Sq,Sk,window,causal", [
-    (256, 256, None, True),
-    (512, 512, None, True),
-    (512, 512, 200, True),     # sliding window
-    (256, 256, None, False),
-    (128, 384, None, True),    # q shorter than k (prefill-tail/decode-ish)
-])
-def test_chunked_attention_matches_ref(Sq, Sk, window, causal):
-    from repro.kernels.flash_attention.ref import attention_chunked
-    B, H, K, D = 2, 4, 2, 32
-    q, = rngs((B, Sq, H, D), seed=40)
-    k, v = rngs((B, Sk, K, D), (B, Sk, K, D), seed=41)
-    out = attention_chunked(q, k, v, causal=causal, window=window,
-                            q_block=128, k_block=128)
-    ref = attention_ref(q, k, v, causal=causal, window=window,
-                        q_offset=Sk - Sq if causal else 0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_chunked_attention_grad_matches_ref():
-    from repro.kernels.flash_attention.ref import attention_chunked
-    B, S, H, K, D = 1, 256, 4, 2, 16
-    q, k, v = rngs((B, S, H, D), (B, S, K, D), (B, S, K, D), seed=42)
-
-    def loss_c(q, k, v):
-        return (attention_chunked(q, k, v, q_block=64, k_block=64) ** 2).sum()
-
-    def loss_r(q, k, v):
-        return (attention_ref(q, k, v) ** 2).sum()
-
-    gc = jax.grad(loss_c, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gc, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-4)

@@ -1,6 +1,6 @@
-"""Sharding-rule unit tests: logical-axis → PartitionSpec mapping for all
-four rule sets, including the divisibility fallback that motivated the
-`zero` rules (§Perf Cell A)."""
+"""Sharding-rule unit tests: logical-axis → PartitionSpec mapping for each
+rule set, and the divisibility fallback that replicates a dim a mesh axis
+does not divide."""
 
 import jax
 import pytest
@@ -9,6 +9,7 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.models.layers import ParamSpec
 from repro.parallel import sharding as shd
+from repro.parallel.ctx import fit_pspec
 
 
 @pytest.fixture(scope="module")
@@ -38,14 +39,6 @@ def test_indivisible_heads_fall_back_to_replication(mesh):
     assert ps == P()          # heads axis dropped — replicated
 
 
-def test_zero_rules_shard_embed_over_everything(mesh):
-    r = shd.make_rules(multi_pod=False, zero=True)
-    ps = shd.spec_to_pspec(spec((64, 3, 16), ("embed", "heads", "head")),
-                           r, mesh)
-    assert ps == P(("data", "model"))      # embed over the whole mesh
-    assert shd.batch_pspec(r) == P(("data", "model"))
-
-
 def test_tp2d_rules_shard_ff_2d_no_batch(mesh):
     r = shd.make_rules(multi_pod=False, tp2d=True)
     ps = shd.spec_to_pspec(spec((8, 64, 16), ("experts", "embed", "ff")),
@@ -57,13 +50,11 @@ def test_tp2d_rules_shard_ff_2d_no_batch(mesh):
 def test_multipod_adds_pod_axis():
     r = shd.make_rules(multi_pod=True)
     assert tuple(r["batch"]) == ("pod", "data")
-    rz = shd.make_rules(multi_pod=True, zero=True)
-    assert tuple(rz["batch"]) == ("pod", "data", "model")
 
 
 def test_mesh_axis_used_once_per_param(mesh):
     """A mesh axis may appear in at most one dim of a PartitionSpec."""
-    r = shd.make_rules(multi_pod=False, zero=True)
+    r = shd.make_rules(multi_pod=False, fsdp=True)
     # embed appears twice (square weight): second occurrence must drop
     ps = shd.spec_to_pspec(spec((64, 64), ("embed", "embed")), r, mesh)
     flat = []
@@ -72,6 +63,20 @@ def test_mesh_axis_used_once_per_param(mesh):
             continue
         flat.extend(e if isinstance(e, tuple) else (e,))
     assert len(flat) == len(set(flat))
+
+
+@pytest.mark.parametrize("pspec,shape,want", [
+    (P("model"), (3,), P()),
+    (P(("data", "model")), (2,), P("data")),
+    (P(("data", "model")), (4,), P(("data", "model"))),
+    (P("data", None), (1, 1), P()),                 # batch-1 step inputs
+    (P("data", "model"), (4, 3), P("data")),        # an odd vocabulary
+    (P(None, "model"), (8, 6), P(None, "model")),
+])
+def test_fit_pspec(mesh, pspec, shape, want):
+    """Mesh axes a dim does not divide by drop out, one dim at a time, and
+    the result carries no trailing None."""
+    assert fit_pspec(pspec, shape, mesh) == want
 
 
 @pytest.mark.parametrize("batch,lead", [(8, "data"), (6, None)])
