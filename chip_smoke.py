@@ -244,9 +244,9 @@ def phase_serve(args) -> None:
     complete = [r for r in done
                 if r.done and len(r.generated) == r.max_new_tokens]
 
-    # the engine's own compiled prefill for request 0, against the f32 forward
+    # the prefill's logits for request 0, against the f32 forward
     with mesh:
-        logits, _ = engine._prefill_fn(len(prompts[0]))(
+        logits, _ = jax.jit(lambda p, b: M.prefill(p, cfg, b, max_len))(
             params, {"tokens": jnp.asarray([prompts[0]], jnp.int32)})
     ref_cfg = cfg.replace(dtype="float32")
     params32 = jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params)

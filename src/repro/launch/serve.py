@@ -63,7 +63,9 @@ def main(argv=None) -> None:
     print(f"arch={cfg.name} served {len(done)} requests, {total} tokens in "
           f"{c.steps} steps ({dt:.1f}s)")
     print(f"slot efficiency "
-          f"{c.slot_steps_active / (c.steps * args.max_batch):.1%}")
+          f"{c.slot_steps_active / (c.steps * args.max_batch):.1%}, "
+          f"{c.overlapped_steps} decodes dispatched over an unread one, "
+          f"{c.discarded_tokens} tokens of finished rows dropped")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,5 @@
-"""Distributed step functions: train_step / prefill_step / serve_step.
+"""Distributed step functions: train_step / prefill_step / serve_step, and
+the row patch that feeds the serve step's device-resident inputs.
 
 Each maker binds (config, mesh, rules) and returns a jitted function with
 explicit in/out shardings (pjit). The trainer, the serving engine and the
@@ -29,6 +30,7 @@ from repro.parallel.ctx import fit_pspec, sharding_ctx
 from repro.train.optimizer import OptConfig, adamw_update, init_opt
 
 __all__ = ["make_train_step", "make_serve_step", "make_prefill_step",
+           "make_row_patch", "row_shardings",
            "train_state_shardings", "abstract_train_state",
            "batch_shardings", "abstract_batch"]
 
@@ -132,34 +134,64 @@ def make_train_step(cfg, mesh, rules, *, opt: OptConfig | None = None,
 
 
 # -------------------------------------------------------------- serve steps
+def row_shardings(mesh, rules, global_batch: int):
+    """Shardings of the per-row tokens (B, 1) and positions (B,) that the
+    serve, prefill and patch steps take and return."""
+    dp = tuple(shd.batch_pspec(rules))[0]
+    B = global_batch
+    return (NamedSharding(mesh, fit_pspec(P(dp, None), (B, 1), mesh)),
+            NamedSharding(mesh, fit_pspec(P(dp), (B,), mesh)))
+
+
 def make_serve_step(cfg, mesh, rules, *, global_batch: int, max_len: int):
-    """One-token decode step over a persistent sharded cache (donated)."""
+    """One greedy decode step over a persistent sharded cache (donated).
+
+    ``serve_step(params, cache, tokens (B, 1), pos (B,))`` returns the
+    argmax tokens (B, 1) int32, the next positions (B,) and the cache, so
+    each step's outputs can be the next step's inputs without a host read.
+    A row at position 0 is idle (a request's first decode is at its
+    prompt's length, at least 1): it stays there and decodes token 0, so
+    the idle rows of a mixture of experts all take one route.
+    """
     cache_sh = jax.tree_util.tree_map(
         lambda p: NamedSharding(mesh, p),
         shd.cache_pspecs(M.cache_shapes(cfg, global_batch, max_len), rules,
                          mesh, cfg))
     specs = M.param_shapes(cfg)
     param_sh = shd.param_shardings(specs, rules, mesh)
-    bp = shd.batch_pspec(rules)
-    dp = tuple(bp)[0]
-    B, V = global_batch, cfg.vocab_size
-    tok_sh = NamedSharding(mesh, fit_pspec(P(dp, None), (B, 1), mesh))
-    pos_sh = NamedSharding(mesh, fit_pspec(P(dp), (B,), mesh))
-    logit_sh = NamedSharding(mesh, fit_pspec(P(dp, "model"), (B, V), mesh))
+    tok_sh, pos_sh = row_shardings(mesh, rules, global_batch)
 
     def serve_step(params, cache, tokens, pos):
         with sharding_ctx(mesh, rules):
             logits, new_cache = M.decode_step(params, cfg, cache, tokens, pos)
-        return logits, new_cache
+        live = pos > 0
+        nxt = jnp.where(live, jnp.argmax(logits, axis=-1), 0)
+        return (nxt.astype(jnp.int32)[:, None], jnp.where(live, pos + 1, 0),
+                new_cache)
 
     return jax.jit(serve_step,
                    in_shardings=(param_sh, cache_sh, tok_sh, pos_sh),
-                   out_shardings=(logit_sh, cache_sh),
+                   out_shardings=(tok_sh, pos_sh, cache_sh),
+                   donate_argnums=(1,))
+
+
+def make_row_patch(mesh, rules, *, global_batch: int):
+    """``row_patch(tokens, pos, row, tok (1, 1), start)`` writes one row's
+    token and position into the decode's inputs. The positions are donated;
+    the tokens are not, since they are a decode's output that the host has
+    yet to read."""
+    def row_patch(tokens, pos, row, tok, start):
+        return tokens.at[row].set(tok[0]), pos.at[row].set(start)
+
+    return jax.jit(row_patch,
+                   out_shardings=row_shardings(mesh, rules, global_batch),
                    donate_argnums=(1,))
 
 
 def make_prefill_step(cfg, mesh, rules, *, global_batch: int, seq_len: int,
                       max_len: int):
+    """``prefill_step(params, batch)`` returns the argmax token after each
+    prompt, (B, 1) int32, and the decode cache of the prompts."""
     cache_sh = jax.tree_util.tree_map(
         lambda p: NamedSharding(mesh, p),
         shd.cache_pspecs(M.cache_shapes(cfg, global_batch, max_len), rules,
@@ -167,16 +199,13 @@ def make_prefill_step(cfg, mesh, rules, *, global_batch: int, seq_len: int,
     specs = M.param_shapes(cfg)
     param_sh = shd.param_shardings(specs, rules, mesh)
     batch_sh = batch_shardings(cfg, mesh, rules)
-    bp = shd.batch_pspec(rules)
-    logit_sh = NamedSharding(
-        mesh, fit_pspec(P(tuple(bp)[0], "model"),
-                         (global_batch, cfg.vocab_size), mesh))
+    tok_sh, _ = row_shardings(mesh, rules, global_batch)
 
     def prefill_step(params, batch):
         with sharding_ctx(mesh, rules):
             logits, cache = M.prefill(params, cfg, batch, max_len)
-        return logits, cache
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None], cache
 
     return jax.jit(prefill_step,
                    in_shardings=(param_sh, batch_sh),
-                   out_shardings=(logit_sh, cache_sh))
+                   out_shardings=(tok_sh, cache_sh))

@@ -93,9 +93,10 @@ def test_decode_at_different_depths_matches_reference(setup):
     refs = [_ref_logits(c, params, seqs[b]) for b in range(3)]
     pos = np.array(lengths, np.int32)
     cache = engine.cache
+    decode = jax.jit(lambda p, c, t, q: M.decode_step(p, cfg, c, t, q))
     for _ in range(steps):
         tok = jnp.asarray(seqs[np.arange(3), pos][:, None])
-        logits, cache = engine.decode(params, cache, tok, jnp.asarray(pos))
+        logits, cache = decode(params, cache, tok, jnp.asarray(pos))
         for b in range(3):
             np.testing.assert_allclose(np.asarray(logits[b]), refs[b][pos[b]],
                                        rtol=TOL, atol=TOL)
