@@ -21,6 +21,7 @@ ARCHS = [
     "mixtral-8x22b",
     "moonshot-v1-16b-a3b",
     "seamless-m4t-large-v2",
+    "kimi-linear-48b-a3b",
 ]
 
 _EXTRA = ["tiny"]  # paper-scale demo model (~100M) for the e2e driver

@@ -38,9 +38,12 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    mla_nope: bool = False          # Kimi Linear's mla_use_nope: the rope
+                                    # parts are not rotated
 
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0            # the router's outputs: every expert
+    experts_held: int = 0           # experts 0..n-1 held here (0 → all)
     num_experts_per_tok: int = 0
     moe_d_ff: int = 0
     num_shared_experts: int = 0     # always-on SwiGLU of that many × moe_d_ff
@@ -57,6 +60,12 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 256
     conv_width: int = 4
+
+    # Kimi Delta Attention (gated delta rule): the published 1-based layers
+    # that are KDA; every other layer is latent attention
+    kda_layers: tuple = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
 
     # hybrid (recurrentgemma): repeating block pattern
     block_pattern: tuple = ()       # e.g. ("rglru", "rglru", "local_attn")
@@ -89,6 +98,12 @@ class ModelConfig:
     @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this chip holds, the first of the
+        router's ``num_experts``."""
+        return self.experts_held or self.num_experts
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -14,7 +14,9 @@ by the roped key part. Its prefill expands the latent to per-head keys and
 values and runs the usual attention; its decode never expands the cache:
 the key up-projection is absorbed into the query and the value
 up-projection into the output, so the scores and the weighted sum read the
-latent rows directly.
+latent rows directly. Kimi Linear's latent attention (``cfg.mla_nope``)
+rotates nothing: its "rope" parts of q and of the shared key stay as
+projected, with the same widths, scale and cache row.
 
 Decode attention reads a cache one of two ways, chosen by the platform of
 the devices the step is compiled for: on TPUs the ragged Pallas kernel
@@ -40,6 +42,8 @@ from repro.parallel import sharding as shd
 
 __all__ = ["attn_specs", "attn_apply", "attn_decode", "cross_attn_apply",
            "mla_specs", "mla_apply", "mla_decode"]
+
+_SCATTER_PIECES = 1024          # pieces one latent-row scatter writes at most
 
 
 def attn_specs(cfg, *, cross: bool = False) -> dict:
@@ -196,7 +200,10 @@ def mla_specs(cfg) -> dict:
 def _rope_pairs(x, positions, cfg):
     """Rotary embedding of adjacent pairs (2i, 2i+1) at frequency i, as the
     published MLA models apply it: the pairs are gathered into halves, then
-    rotated. x: (B, S, heads, R); positions: (B, S)."""
+    rotated. x: (B, S, heads, R); positions: (B, S). With ``cfg.mla_nope``
+    (Kimi Linear) nothing is rotated and x is returned as it is."""
+    if cfg.mla_nope:
+        return x
     x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     sin, cos = rotary_embedding(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
     return apply_rope(x, sin, cos)
@@ -260,10 +267,17 @@ def mla_decode(p: dict, x: jax.Array, cache: jax.Array, pos: jax.Array, cfg,
         w = math.gcd(C, cfg.qk_rope_head_dim)
         pieces = cache.reshape(*cache.shape[:-1], -1, w)
         n = pieces.shape[-2]
-        at = (jnp.arange(B)[:, None], slot[:, None], jnp.arange(n)[None, :])
-        if layer is not None:
-            at = (layer, *at)
-        pieces = pieces.at[at].set(row[:, 0].reshape(B, n, w).astype(cache.dtype))
+        new = row[:, 0].reshape(B, n, w).astype(cache.dtype)
+        # and by groups of rows of at most _SCATTER_PIECES pieces: one
+        # scatter of more makes it copy the whole cache
+        per = max(1, _SCATTER_PIECES // n)
+        for b0 in range(0, B, per):
+            rows = slice(b0, b0 + per) if per < B else slice(None)
+            at = (jnp.arange(B)[rows, None], slot[rows, None],
+                  jnp.arange(n)[None, :])
+            if layer is not None:
+                at = (layer, *at)
+            pieces = pieces.at[at].set(new[rows])
         cache = pieces.reshape(cache.shape)
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
                            p["wk_b"].astype(x.dtype))

@@ -3,14 +3,17 @@ every assigned architecture family.
 
 A stack whose layer kinds, after the leading dense layers, are all one kind
 is stacked and scanned (depth-independent HLO ⇒ fast compiles); any other
-pattern (recurrentgemma's) unrolls, one dict per layer. Each layer body is
-rematerialised. A mixture-of-experts model's leading dense layers
-(``first_dense_layers``) form a group of their own, ``dense_layers``,
+pattern (recurrentgemma's, Kimi Linear's) unrolls, one dict per layer. Each
+layer body is rematerialised. A mixture-of-experts model's leading dense
+layers (``first_dense_layers``) form a group of their own, ``dense_layers``,
 stacked like ``layers`` but applied unrolled ahead of the scanned MoE
 layers. In the params and the cache a group is either stacked
 ({group: {leaf: (L, ...)}}) or holds one dict per layer
 ({group: {"layer_i": {leaf: ...}}}); :func:`stacked` tells a cache's apart
-by that structure.
+by that structure. A hybrid stack's cache tree holds two kinds of state
+side by side: Kimi Linear's KDA layers a float32 recurrent state and conv
+tails, (B, H, K, K) and (B, W − 1, 3, H, K), whatever the context; its
+latent-attention layers a latent row per position, (B, Smax, C + R).
 All public functions treat ``cfg`` as static (hashable frozen dataclass).
 """
 
@@ -22,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.models import kda as kda_mod
 from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.models.layers import (ParamSpec, abstract_tree, init_tree,
@@ -185,6 +189,8 @@ def _layer_cache_shapes(cfg, kind: str, batch: int, max_len: int, dtype):
         return ssm_cache_shapes(cfg, batch, dtype)
     if kind == "rglru":
         return rglru_cache_shapes(cfg, batch, dtype)
+    if kind in ("kda", "kda_dense"):
+        return kda_mod.kda_cache_shapes(cfg, batch, dtype)
     if cfg.kv_lora_rank:        # one latent row a token, no head axis
         width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
         return {"latent": ((batch, max_len, width), dtype)}
@@ -236,8 +242,9 @@ def abstract_cache(cfg, batch: int, max_len: int, dtype=None):
 
 # ------------------------------------------------------------------- decode
 def decode_step(params, cfg, cache, tokens, pos):
-    """One decode step. tokens: (B, 1) int32; pos: scalar int32 (absolute
-    position of this token). Returns (logits (B, V) f32, new_cache)."""
+    """One decode step. tokens: (B, 1) int32; pos: (B,) int32, each row's
+    absolute position of this token (rows sit at different depths).
+    Returns (logits (B, V) f32, new_cache)."""
     kinds = tfm.layer_kinds(cfg)
     dt = compute_dtype(cfg)
     x = take_embedding(params["embed"], tokens, dt)

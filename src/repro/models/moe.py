@@ -9,6 +9,12 @@ experts. Either way the gates are normalised to sum to one and scaled by
 ``routed_scaling``. Shared experts (``num_shared_experts``) are one SwiGLU
 of that many times ``moe_d_ff`` that every token passes through.
 
+A layer may hold a share of the experts (``experts_held``, expert
+parallelism by share): the router keeps all ``num_experts`` outputs and
+chooses among all of them, and the layer computes the part of the result
+that its own experts, the first ``experts_held``, give; the chips that hold
+the others give the rest. Shared experts are computed on every chip.
+
 Serving (prefill and decode, :func:`moe_serve`) is dropless: each token's
 k assignments are sorted by expert and run through grouped matrix products
 (``jax.lax.ragged_dot``), so the work is that of the assignments and each
@@ -42,13 +48,14 @@ _EXPERT_WEIGHTS = ("we_gate", "we_up", "we_down")
 
 def moe_specs(cfg) -> dict:
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    Eh = cfg.experts_here
     s = {
         "router": ParamSpec((D, E), ("embed", "experts")),
-        "we_gate": ParamSpec((E, D, F), ("experts", "embed", "ff"),
+        "we_gate": ParamSpec((Eh, D, F), ("experts", "embed", "ff"),
                              fan_in_axes=(1,)),
-        "we_up": ParamSpec((E, D, F), ("experts", "embed", "ff"),
+        "we_up": ParamSpec((Eh, D, F), ("experts", "embed", "ff"),
                            fan_in_axes=(1,)),
-        "we_down": ParamSpec((E, F, D), ("experts", "ff", "embed"),
+        "we_down": ParamSpec((Eh, F, D), ("experts", "ff", "embed"),
                              fan_in_axes=(1,)),
     }
     if cfg.router == "sigmoid":
@@ -102,9 +109,13 @@ def moe_serve(p: dict, x: jax.Array, cfg,
               layer: jax.Array | None = None) -> jax.Array:
     """Dropless MoE for prefill and decode. x: (B, S, D) → (B, S, D). With
     ``layer``, the expert weights in ``p`` are stacked over layers, (L, E,
-    ...), and this layer's index picks its experts."""
+    ...), and this layer's index picks its experts. Where the layer holds a
+    share of the experts, the assignments to the others sort after every
+    held expert's, in no group (``bincount`` leaves them out of the
+    sizes), and add nothing."""
     B, S, D = x.shape
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    E, k = cfg.experts_here, cfg.num_experts_per_tok
+    partial = E < cfg.num_experts
     xt = x.reshape(B * S, D)
     weights = [p[name] for name in _EXPERT_WEIGHTS]
     with jax.named_scope("moe.route"):
@@ -126,20 +137,24 @@ def moe_serve(p: dict, x: jax.Array, cfg,
         h = swiglu(grouped(xs, wg), grouped(xs, wu))
         ys = grouped(h, wd)                                        # (T·k, D)
         ys = jnp.take(ys, jnp.argsort(order), axis=0).reshape(B * S, k, D)
+        if partial:             # rows in no group: whatever they hold, 0
+            ys = jnp.where((sel < E)[..., None], ys, 0)
         y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32), gates)
     y = y.astype(x.dtype).reshape(B, S, D)
     return y + _shared(p, x, cfg)
 
 
 def moe_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """Training: x: (B, S, D) → (out (B, S, D), aux_loss scalar)."""
+    """Training: x: (B, S, D) → (out (B, S, D), aux_loss scalar). A layer
+    holding a share of the experts dispatches to its own."""
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     capacity = max(int(S * k / E * cfg.capacity_factor), 1)
 
     gate_vals, sel, probs = route(p, x, cfg)                      # (B,S,k)
 
-    onehot = jax.nn.one_hot(sel, E, dtype=jnp.float32)            # (B,S,k,E)
+    routed = jax.nn.one_hot(sel, E, dtype=jnp.float32)            # (B,S,k,E)
+    onehot = routed[..., :cfg.experts_here]
     assign = jnp.einsum("bske->bse", onehot)                      # 0/1
     # position of each token within its expert's buffer (per batch row)
     pos_in_expert = jnp.cumsum(assign, axis=1) - assign           # (B,S,E)
@@ -160,7 +175,7 @@ def moe_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
                             ("batch", "seq", "act_embed"))
 
     # load-balancing aux loss
-    frac_dispatch = jnp.mean(assign, axis=(0, 1))                 # (E,)
+    frac_dispatch = jnp.mean(routed.sum(2), axis=(0, 1))          # (E,)
     frac_prob = jnp.mean(probs, axis=(0, 1))                      # (E,)
     aux = E * jnp.sum(frac_dispatch * frac_prob) * cfg.router_aux_coef
     return out, aux.astype(jnp.float32)

@@ -7,13 +7,19 @@ One block type per layer "kind":
               a mixture-of-experts model (``first_dense_layers``)
   local_attn  sliding-window attention (hybrid archs) + FFN
   rglru       RG-LRU recurrent mixer + FFN
+  kda         Kimi Delta Attention (gated delta-rule linear attention) +
+              FFN (the MoE layer in a mixture-of-experts model)
+  kda_dense   Kimi Delta Attention + dense MLP (a leading dense layer)
   ssm         Mamba-2 SSD mixer (no FFN — the mamba block subsumes it)
   enc_attn    bidirectional self-attention (encoder) + FFN
   cross       causal self-attention + cross-attention + FFN (decoder of
               an encoder-decoder)
 
 Self-attention is latent attention (MLA) where ``cfg.kv_lora_rank`` is set;
-its cache is one ``latent`` leaf of rows shared by the heads.
+its cache is one ``latent`` leaf of rows shared by the heads. Where
+``cfg.kda_layers`` lists the published KDA layers (Kimi Linear), every other
+layer is latent attention; a KDA layer's cache is its conv tails and its
+recurrent state.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import kda as kda_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
@@ -34,6 +41,11 @@ __all__ = ["layer_kinds", "block_specs", "block_apply", "block_decode",
 def layer_kinds(cfg, *, encoder: bool = False) -> list[str]:
     if encoder:
         return ["enc_attn"] * cfg.encoder_layers
+    if cfg.kda_layers:
+        lead = cfg.first_dense_layers
+        return [("kda" if i + 1 in cfg.kda_layers else "attn")
+                + ("_dense" if i < lead else "")
+                for i in range(cfg.num_layers)]
     if cfg.family == "ssm":
         return ["ssm"] * cfg.num_layers
     if cfg.family == "hybrid":
@@ -46,7 +58,11 @@ def layer_kinds(cfg, *, encoder: bool = False) -> list[str]:
 
 
 def _moe(cfg, kind: str) -> bool:
-    return cfg.num_experts > 0 and kind in ("attn", "local_attn")
+    return cfg.num_experts > 0 and kind in ("attn", "local_attn", "kda")
+
+
+def _kda(kind: str) -> bool:
+    return kind in ("kda", "kda_dense")
 
 
 # ------------------------------------------------------------------- specs
@@ -63,7 +79,9 @@ def mlp_specs(cfg) -> dict:
 def block_specs(cfg, kind: str) -> dict:
     D = cfg.d_model
     s: dict = {"pre_norm": ParamSpec((D,), ("embed",), init="ones")}
-    if kind in ("attn", "attn_dense") and cfg.kv_lora_rank:
+    if _kda(kind):
+        s.update(kda_mod.kda_specs(cfg))
+    elif kind in ("attn", "attn_dense") and cfg.kv_lora_rank:
         s.update(attn.mla_specs(cfg))
     elif kind in ("attn", "attn_dense", "local_attn", "enc_attn", "cross"):
         s.update(attn.attn_specs(cfg))
@@ -124,6 +142,8 @@ def block_apply(p: dict, x: jax.Array, cfg, kind: str, *,
         return x + ssm_mod.ssm_apply(p, h, cfg), jnp.float32(0.0)
     if kind == "rglru":
         x = x + rglru_mod.rglru_apply(p, h, cfg)
+    elif _kda(kind):
+        x = x + kda_mod.kda_apply(p, h, cfg)[0]
     elif cfg.kv_lora_rank and kind in ("attn", "attn_dense"):
         x = x + attn.mla_apply(p, h, cfg)[0]
     else:
@@ -151,6 +171,10 @@ def block_prefill(p: dict, x: jax.Array, cfg, kind: str, max_len: int, *,
         out, cache = _rglru_prefill(p, h, cfg)
         x = x + out
         x, aux = _ffn(p, x, cfg, kind, serving=True, layer=layer)
+        return x, cache, aux
+    if _kda(kind):
+        out, cache = kda_mod.kda_apply(p, h, cfg)
+        x, aux = _ffn(p, x + out, cfg, kind, serving=True, layer=layer)
         return x, cache, aux
     if cfg.kv_lora_rank:
         out, rows = attn.mla_apply(p, h, cfg)
@@ -228,6 +252,13 @@ def _rglru_prefill(p, h, cfg):
 
 
 # ------------------------------------------------------------------- decode
+# the one-token steps of the mixers whose cache is a recurrent state
+_RECURRENT_DECODE = {"ssm": ssm_mod.ssm_decode,
+                     "rglru": rglru_mod.rglru_decode,
+                     "kda": kda_mod.kda_decode,
+                     "kda_dense": kda_mod.kda_decode}
+
+
 def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
                  kind: str, layer: jax.Array | None = None):
     """One-token step. x: (B, 1, D). ``cache`` holds this layer's leaves or,
@@ -242,11 +273,11 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg,
         return new if layer is None else c.at[layer].set(new)
 
     h = rms_norm(x, p["pre_norm"], cfg.norm_eps)
-    if kind in ("ssm", "rglru"):
-        step = ssm_mod.ssm_decode if kind == "ssm" else rglru_mod.rglru_decode
-        out, new = step(p, h, {k: read(c) for k, c in cache.items()}, cfg)
+    if kind in _RECURRENT_DECODE:
+        out, new = _RECURRENT_DECODE[kind](
+            p, h, {k: read(c) for k, c in cache.items()}, cfg)
         x = x + out
-        if kind == "rglru":
+        if kind != "ssm":
             x, _ = _ffn(p, x, cfg, kind, serving=True, layer=layer)
         return x, {k: write(cache[k], new[k]) for k in cache}
     if cfg.kv_lora_rank:

@@ -12,7 +12,7 @@ DATA_PLANE_MODULES = {"test_kernels", "test_kernels_smoke", "test_arch_smoke",
                       "test_train_serve", "test_sharding_rules",
                       "test_tpu_compile", "test_spans", "test_decode_cache",
                       "test_mla_moe", "test_decode_attention",
-                      "test_serve_pipeline"}
+                      "test_serve_pipeline", "test_kda"}
 
 
 def pytest_collection_modifyitems(items):

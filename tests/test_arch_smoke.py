@@ -50,6 +50,7 @@ def test_full_config_matches_assignment(arch):
         "mixtral-8x22b": (56, 6144, 32768),
         "moonshot-v1-16b-a3b": (27, 2048, 163840),
         "seamless-m4t-large-v2": (24, 1024, 256206),
+        "kimi-linear-48b-a3b": (27, 2304, 163840),
     }[arch]
     assert (cfg.num_layers, cfg.d_model, cfg.vocab_size) == published
 
@@ -106,7 +107,8 @@ def test_stack_layout_follows_layer_kinds(arch):
     """The params and the cache hold a stacked ``layers`` group (and a
     stacked ``dense_layers`` group of the leading dense layers) exactly
     when the layer kinds after those are all one kind; otherwise one
-    ``layer_i`` dict per layer. Only the hybrid pattern unrolls."""
+    ``layer_i`` dict per layer. Only the hybrid patterns (recurrentgemma's,
+    Kimi Linear's) unroll."""
     from repro.models import transformer as tfm
     from repro.models.layers import ParamSpec
 
@@ -146,7 +148,7 @@ def test_stack_layout_follows_layer_kinds(arch):
 
 @pytest.mark.parametrize("arch", [
     "granite-8b", "mamba2-130m", "recurrentgemma-2b", "mixtral-8x22b",
-    "moonshot-v1-16b-a3b"])
+    "moonshot-v1-16b-a3b", "kimi-linear-48b-a3b"])
 def test_prefill_decode_consistency(arch):
     """greedy decode over [prefill(x[:n]), step(x[n])] ≈ prefill(x[:n+1]) —
     the cache is a faithful summary of the prefix."""

@@ -21,11 +21,14 @@ RULES = shd.make_rules(multi_pod=False)
 B, MAX_LEN = 3, 32
 
 
-def _moonshot_smoke():
-    """moonshot-v1-16b-a3b at the benchmark's tiny sizes (latent attention,
-    a dense lead layer, shared experts), as ``tests/test_mla_moe.py``."""
+def _bench_smoke(name):
+    """A benchmark configuration at its tiny sizes, as ``tests/test_mla_moe.py``
+    and ``tests/test_kda.py`` build it: moonshot-v1-16b-a3b (latent
+    attention, a dense lead layer, shared experts) and kimi-linear-48b-a3b
+    (KDA layers' recurrent state beside latent rows, half the experts
+    held)."""
     raw = json.load(open(os.path.join(ROOT, "bench", "configs",
-                                      "moonlight-16b-a3b.json")))
+                                      name + ".json")))
     c = {**raw, **raw["tiny"]}
     fields = {f: c[k] for f, k in raw["program"]["fields"].items()}
     return configs.get_smoke(raw["program"]["arch"]).replace(**fields)
@@ -33,7 +36,8 @@ def _moonshot_smoke():
 
 CFGS = {"granite-8b": lambda: configs.get_smoke("granite-8b").replace(
             dtype="float32"),
-        "moonshot-v1-16b-a3b": _moonshot_smoke}
+        "moonshot-v1-16b-a3b": lambda: _bench_smoke("moonlight-16b-a3b"),
+        "kimi-linear-48b-a3b": lambda: _bench_smoke("kimi-linear-48b-a3b")}
 
 
 @pytest.fixture(scope="module", params=sorted(CFGS))

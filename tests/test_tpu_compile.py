@@ -171,6 +171,37 @@ def test_decode_updates_cache_in_place(topo, arch):
         n // leaf.shape[0] for n, (_, leaf) in zip(nbytes, leaves))
 
 
+def test_kimi_linear_decode_keeps_its_cache_in_place(topo):
+    """The kimi-linear-48b-a3b.serve_reason_b128 cell's decode (8 unrolled
+    layers, 32 of 256 experts held, 128 rows of 4096): each KDA state and
+    conv tail rewritten in its own buffer, each latent cache written in
+    place (its rows in scatters of at most 1024 pieces: one of more copies
+    the whole cache), one kernel call per latent-attention layer, no
+    temporary as large as one layer's state, and the step beside a second
+    cache (the splice's) within the chip."""
+    cfg = configs.get("kimi-linear-48b-a3b").replace(num_layers=8,
+                                                      experts_held=32)
+    B, max_len = 128, 4096
+    cache, compiled = _compile_decode(
+        cfg, make_mesh(topo.devices[:1], (1, 1)), B, max_len)
+    leaves = jax.tree_util.tree_leaves(cache)
+    hlo = compiled.as_text()
+    ops = {(op, tuple(int(d) for d in dims.split(",") if d))
+           for dims, op in _INSTR.findall(hlo)}
+    shapes = {leaf.shape for leaf in leaves}
+    assert not {(op, s) for op, s in ops
+                if op in ("copy", "copy-start") and s in shapes}
+    assert _decode_attention_calls(hlo) == 2
+    m = compiled.memory_analysis()
+    nbytes = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves)
+    assert m.alias_size_in_bytes == nbytes
+    state = min(leaf.size * leaf.dtype.itemsize for leaf in leaves
+                if leaf.dtype == jnp.float32)
+    assert m.temp_size_in_bytes < state
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes + nbytes < \
+        HBM_BYTES
+
+
 # granite's 8 KV heads split over the model axis; Moonlight's latent cache
 # has no head axis, so its slots are split there and the shards' softmaxes
 # merged
